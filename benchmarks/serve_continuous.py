@@ -49,6 +49,7 @@ import numpy as np
 from repro.api import HyperOffloadSession, OffloadConfig
 from repro.api.config import PrefixCacheConfig, TelemetryConfig
 from repro.configs import REGISTRY
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.obs import OverlapAnalyzer
 from repro.offload.kvcache import worst_case_page_bytes
@@ -591,6 +592,7 @@ def main() -> None:
     if args.smoke:
         args.requests = min(args.requests, 10)
         args.out = args.out or "BENCH_serving.json"
+    enable_compile_cache()
 
     cfg = REGISTRY[args.arch].reduced()
     model = build_model(cfg)

@@ -1,8 +1,14 @@
 """Serving launcher: one continuous-batching serve over a Poisson trace,
 with the telemetry front door exposed as flags.
 
-    PYTHONPATH=src python launch/serve.py [--mode continuous|kv_offload]
+    PYTHONPATH=src python launch/serve.py [--smoke]
+        [--mode continuous|kv_offload]
         [--trace-out TRACE.json] [--stats-json STATS.json]
+
+The model is ``--arch`` at its published widths and depth, with random
+bfloat16 weights and a bfloat16 KV cache (a 3.8B model's float32 weights
+alone fill a 16 GB chip). ``--smoke`` serves the arch's reduced float32
+config instead, small enough for the CPU.
 
 ``--trace-out`` enables the session's telemetry block
 (``OffloadConfig.telemetry``) and writes the Chrome trace-event /
@@ -27,14 +33,10 @@ import argparse
 import json
 import time
 
-import jax
-import jax.numpy as jnp
-
-from repro.api import HyperOffloadSession, OffloadConfig
+from repro.api import HyperOffloadSession
 from repro.api.config import TelemetryConfig
-from repro.configs import REGISTRY
-from repro.models.model import build_model
-from repro.offload.kvcache import worst_case_page_bytes
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import load_model, offload_config
 from repro.sched import poisson_trace
 from repro.slo import SLOConfig, attainment_summary
 
@@ -42,6 +44,8 @@ from repro.slo import SLOConfig, attainment_summary
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", default="phi3-mini-3.8b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's reduced float32 config")
     ap.add_argument("--mode", choices=("continuous", "kv_offload"),
                     default="kv_offload")
     ap.add_argument("--requests", type=int, default=8)
@@ -68,26 +72,20 @@ def main() -> None:
     if args.slo and args.interactive_fraction is None:
         args.interactive_fraction = 0.35   # --slo alone still demos SLOs
 
-    cfg = REGISTRY[args.arch].reduced()
-    model = build_model(cfg)
-    params = model.init(jax.random.key(0))
+    enable_compile_cache()
+    model, params, dtype = load_model(args.arch, smoke=args.smoke)
+    cfg = model.cfg
 
-    kwargs = dict(mode=args.mode, max_batch=args.max_batch,
-                  max_seq=args.max_seq, prefill_budget=2)
-    if args.mode == "kv_offload":
-        # device tier ≈ half the running batch: cold pages spill to host
-        # and prefetch back — the traffic the trace is interesting for
-        row = worst_case_page_bytes(
-            model.cache_specs(1, args.max_seq, jnp.float32))
-        kwargs.update(device_capacity=max(1, args.max_batch // 2) * row,
-                      host_capacity=2 * args.max_batch * row)
+    extra = dict(prefill_budget=2)
     if args.trace_out is not None:
-        kwargs["telemetry"] = TelemetryConfig(enable=True,
-                                              trace_path=args.trace_out)
+        extra["telemetry"] = TelemetryConfig(enable=True,
+                                             trace_path=args.trace_out)
     if args.slo:
-        kwargs["slo"] = SLOConfig(enable=True)
+        extra["slo"] = SLOConfig(enable=True)
 
-    session = HyperOffloadSession(OffloadConfig(**kwargs))
+    session = HyperOffloadSession(offload_config(
+        model, mode=args.mode, max_batch=args.max_batch,
+        max_seq=args.max_seq, cache_dtype=dtype, **extra))
     sched = session.scheduler(model, params)
     rate = args.rate * (args.overload or 1.0)
     trace = poisson_trace(args.requests, rate=rate,

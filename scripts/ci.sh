@@ -92,7 +92,7 @@ EOF
 # traced smoke serve: capture one Chrome trace through the launcher's
 # telemetry flags and validate it against the repro.obs schema checker
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
-    python launch/serve.py --requests 4 --trace-out TRACE_smoke.json
+    python launch/serve.py --smoke --requests 4 --trace-out TRACE_smoke.json
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
     python -m repro.obs.check TRACE_smoke.json
 rm -f TRACE_smoke.json

@@ -81,7 +81,7 @@ def flash_attention_pallas(
     logit_cap: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, hq, s, d = q.shape
     hkv, t = k.shape[1], k.shape[2]

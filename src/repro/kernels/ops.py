@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On the CPU test/dry-run host the kernels execute in interpret mode; on real
-TPU set ``interpret=False`` (the module-level knob) to compile them. The
-model substrate calls these through ``repro.models.runtime`` dispatch.
+The kernels compile for the TPU. XLA:CPU cannot run a Mosaic kernel, so
+on the CPU backend (tests, dry runs) they execute in interpret mode;
+``_interpret`` makes that choice, at trace time, for every wrapper here.
+The model substrate calls these through ``repro.models.runtime`` dispatch.
 """
 
 from __future__ import annotations
@@ -19,8 +20,10 @@ from repro.kernels.paged_attention import (
 )
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
-# CPU backend executes Pallas in interpret mode only.
-INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """Interpret the kernels only where they cannot compile: on the CPU."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "logit_cap", "causal"))
@@ -35,7 +38,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vt = v.transpose(0, 2, 1, 3)
     out = flash_attention_pallas(qt, kt, vt, scale=scale, causal=causal,
                                  window=window, logit_cap=logit_cap,
-                                 interpret=INTERPRET)
+                                 interpret=_interpret())
     return out.transpose(0, 2, 1, 3)
 
 
@@ -49,7 +52,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kt = k.transpose(0, 2, 1, 3)              # (B, Hkv, C, D)
     vt = v.transpose(0, 2, 1, 3)
     out = decode_attention_pallas(q3, kt, vt, pos, scale=scale,
-                                  logit_cap=logit_cap, interpret=INTERPRET)
+                                  logit_cap=logit_cap, interpret=_interpret())
     return out[:, None]
 
 
@@ -60,15 +63,16 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            tail_len: jax.Array, *, scale: float,
                            logit_cap: Optional[float] = None) -> jax.Array:
     """Paged decode attention over pool pages + device tail: q (B,Hq,D),
-    pages (P,B,page,Hkv,D), table (n,) → (B,Hq,D). Retraces only when the
-    table *length* changes (one flush per page_size tokens) — the slot
-    values ride in as data via scalar prefetch."""
+    pages (P,B,Hkv,page,D), tail (B,Hkv,page,D), table (n,) → (B,Hq,D).
+    Retraces only when the table *length* changes (one flush per
+    page_size tokens) — the slot values ride in as data via scalar
+    prefetch."""
     return paged_decode_attention_pallas(
         q, k_pages, v_pages, page_table, k_tail, v_tail, tail_len,
-        scale=scale, logit_cap=logit_cap, interpret=INTERPRET)
+        scale=scale, logit_cap=logit_cap, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x: jax.Array, a: jax.Array, b_mat: jax.Array, c_mat: jax.Array,
              chunk: int) -> Tuple[jax.Array, jax.Array]:
-    return ssd_scan_pallas(x, a, b_mat, c_mat, chunk, interpret=INTERPRET)
+    return ssd_scan_pallas(x, a, b_mat, c_mat, chunk, interpret=_interpret())

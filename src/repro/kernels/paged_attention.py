@@ -17,8 +17,9 @@ in as a **scalar-prefetch operand** and the k/v BlockSpec index maps walk
 it: grid step ``ik`` pulls page ``page_table[ik]`` straight from the
 paged buffer, the final grid step covers the device tail, and one online
 softmax merges all of it — no materialized contiguous copy at any point.
-Tail validity (``arange(page) < tail_len``) streams in as a mask row, so
-an empty, partial, or just-flushed tail needs no kernel recompile.
+Tail validity (``arange(page) < tail_len``) is built in the kernel from
+``tail_len``, a second scalar-prefetch operand, so an empty, partial, or
+just-flushed tail needs no kernel recompile.
 ``kernels.ref.paged_decode_attention_ref`` is the lowering-free oracle
 (and the CPU serving fallback — bit-identical to the legacy gather path).
 """
@@ -81,7 +82,7 @@ def decode_attention_pallas(
     scale: float,
     logit_cap: Optional[float] = None,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, hq, d = q.shape
     hkv, c = k.shape[1], k.shape[2]
@@ -130,8 +131,8 @@ def decode_attention_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, kt_ref, vt_ref,
-                         mask_ref, o_ref, m_scr, l_scr, acc_scr,
+def _paged_decode_kernel(pt_ref, tl_ref, q_ref, k_ref, v_ref, kt_ref,
+                         vt_ref, o_ref, m_scr, l_scr, acc_scr,
                          *, scale: float, logit_cap: Optional[float],
                          n_blocks: int):
     ik = pl.program_id(1)
@@ -147,16 +148,17 @@ def _paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, kt_ref, vt_ref,
     # page the index map prefetched via pt_ref (n_blocks is static, so
     # this select folds per grid position)
     is_tail = ik == n_blocks - 1
-    k = jnp.where(is_tail, kt_ref[0, :, 0, :], k_ref[0, 0, :, 0, :])
-    v = jnp.where(is_tail, vt_ref[0, :, 0, :], v_ref[0, 0, :, 0, :])
+    k = jnp.where(is_tail, kt_ref[0, 0], k_ref[0, 0, 0])
+    v = jnp.where(is_tail, vt_ref[0, 0], v_ref[0, 0, 0])
     k = k.astype(jnp.float32)                            # (page, D)
     v = v.astype(jnp.float32)
-    valid = mask_ref[0]                                  # (page,) bool
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k.shape[0]), 1)
+    valid = jnp.logical_or(jnp.logical_not(is_tail), slot < tl_ref[0])
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, page)
     if logit_cap is not None:
         s = logit_cap * jnp.tanh(s / logit_cap)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -174,23 +176,26 @@ def _paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, kt_ref, vt_ref,
 
 def paged_decode_attention_pallas(
     q: jax.Array,           # (B, Hq, D)
-    k_pages: jax.Array,     # (P, B, page, Hkv, D) — page buffer slots
+    k_pages: jax.Array,     # (P, B, Hkv, page, D) — page buffer slots
     v_pages: jax.Array,
     page_table: jax.Array,  # (n,) int32 — slots to attend, in order
-    k_tail: jax.Array,      # (B, page, Hkv, D)
+    k_tail: jax.Array,      # (B, Hkv, page, D)
     v_tail: jax.Array,
     tail_len: jax.Array,    # scalar int32
     *,
     scale: float,
     logit_cap: Optional[float] = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Fused paged decode: attend directly over the non-contiguous pages
     named by ``page_table`` plus the device tail, in one online-softmax
     pass (see module docstring). The page dimension is the kv block, so
-    pool-transfer granularity and kernel tiling coincide."""
+    pool-transfer granularity and kernel tiling coincide. Pages are laid
+    out head-major (``Hkv`` before ``page``) so that every block's last
+    two dimensions are a whole ``(page, D)`` tile, which the TPU compiler
+    requires whatever ``page`` and ``D`` are."""
     b, hq, d = q.shape
-    page, hkv = k_tail.shape[1], k_tail.shape[2]
+    hkv, page = k_tail.shape[1], k_tail.shape[2]
     g = hq // hkv
     if k_pages.shape[0] == 0:
         # the k/v operands need at least one indexable slot even when the
@@ -204,34 +209,31 @@ def paged_decode_attention_pallas(
     # still runs — park it on slot 0 so the prefetch stays in range
     pt = jnp.concatenate([jnp.asarray(page_table, jnp.int32),
                           jnp.zeros((1,), jnp.int32)])
-    mask = jnp.concatenate(
-        [jnp.ones((n, page), bool),
-         (jnp.arange(page) < tail_len)[None, :]], axis=0)
+    tl = jnp.reshape(jnp.asarray(tail_len, jnp.int32), (1,))
     qg = q.reshape(b, hkv, g, d)
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                logit_cap=logit_cap, n_blocks=n_blocks)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b * hkv, n_blocks),
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
-                         lambda bh, ik, pt: (bh // hkv, bh % hkv, 0, 0)),
-            pl.BlockSpec((1, 1, page, 1, d),
-                         lambda bh, ik, pt: (pt[ik], bh // hkv, 0,
-                                             bh % hkv, 0)),
-            pl.BlockSpec((1, 1, page, 1, d),
-                         lambda bh, ik, pt: (pt[ik], bh // hkv, 0,
-                                             bh % hkv, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bh, ik, pt: (bh // hkv, 0, bh % hkv, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bh, ik, pt: (bh // hkv, 0, bh % hkv, 0)),
-            pl.BlockSpec((1, page), lambda bh, ik, pt: (ik, 0)),
+                         lambda bh, ik, pt, tl: (bh // hkv, bh % hkv, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page, d),
+                         lambda bh, ik, pt, tl: (pt[ik], bh // hkv,
+                                                 bh % hkv, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page, d),
+                         lambda bh, ik, pt, tl: (pt[ik], bh // hkv,
+                                                 bh % hkv, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda bh, ik, pt, tl: (bh // hkv, bh % hkv, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda bh, ik, pt, tl: (bh // hkv, bh % hkv, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bh, ik, pt: (bh // hkv, bh % hkv,
-                                                   0, 0)),
+                               lambda bh, ik, pt, tl: (bh // hkv, bh % hkv,
+                                                       0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
@@ -243,5 +245,5 @@ def paged_decode_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
-    )(pt, qg, k_pages, v_pages, k_tail, v_tail, mask)
+    )(pt, tl, qg, k_pages, v_pages, k_tail, v_tail)
     return out.reshape(b, hq, d)

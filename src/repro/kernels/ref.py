@@ -75,10 +75,10 @@ def decode_attention_ref(
 
 def paged_decode_attention_ref(
     q: jax.Array,           # (B, Hq, D) — one token per sequence
-    k_pages: jax.Array,     # (P, B, page, Hkv, D) — page-resident slots
+    k_pages: jax.Array,     # (P, B, Hkv, page, D) — page-resident slots
     v_pages: jax.Array,
     page_table: jax.Array,  # (n,) int — slots to attend over, in order
-    k_tail: jax.Array,      # (B, page, Hkv, D) — device tail buffer
+    k_tail: jax.Array,      # (B, Hkv, page, D) — device tail buffer
     v_tail: jax.Array,
     tail_len: jax.Array,    # scalar int — valid tokens in the tail
     *,
@@ -89,24 +89,38 @@ def paged_decode_attention_ref(
 
     The lowering-free oracle for ``kernels.paged_attention``'s
     paged-decode kernel: gathers ``k_pages[page_table]`` and then runs
-    *exactly* the two-segment merged-softmax math of
-    ``offload.kvcache._paged_attend`` (scores per segment, tail mask at
-    ``tail_len``, one concatenated softmax) — with ``logit_cap=None``
-    the output is bit-for-bit the gather path's, which is what makes
-    codec-"none" serving token-identical when the fused path replaces
-    the per-step gather/concat round trip."""
+    ``merged_pages_attention``, the math ``offload.kvcache``'s gather
+    path runs too — so the output is bit-for-bit the gather path's,
+    which is what makes codec-"none" serving token-identical when the
+    fused path replaces the per-step gather/concat round trip."""
+    return merged_pages_attention(
+        q, k_pages[page_table], v_pages[page_table], k_tail, v_tail,
+        tail_len, scale=scale, logit_cap=logit_cap)
+
+
+def merged_pages_attention(
+    q: jax.Array,           # (B, Hq, D)
+    k_sel: jax.Array,       # (n, B, Hkv, page, D) — selected pages, in order
+    v_sel: jax.Array,
+    k_tail: jax.Array,      # (B, Hkv, page, D)
+    v_tail: jax.Array,
+    tail_len: jax.Array,    # scalar int — valid tokens in the tail
+    *,
+    scale: float,
+    logit_cap: Optional[float] = None,
+) -> jax.Array:
+    """Exact attention over [pages ++ tail]: scores per segment, tail
+    masked at ``tail_len``, one concatenated softmax. Pages and tail are
+    head-major, the layout ``PagedKVCache`` stores and the kernel tiles."""
     b, hq, d = q.shape
-    page, hkv = k_tail.shape[1], k_tail.shape[2]
+    n, _, hkv, page, _ = k_sel.shape
     g = hq // hkv
-    kp = k_pages[page_table]                  # (n, B, page, Hkv, D)
-    vp = v_pages[page_table]
-    n = kp.shape[0]
-    k_flat = kp.transpose(1, 0, 2, 3, 4).reshape(b, n * page, hkv, d)
-    v_flat = vp.transpose(1, 0, 2, 3, 4).reshape(b, n * page, hkv, d)
+    k_flat = k_sel.transpose(1, 2, 0, 3, 4).reshape(b, hkv, n * page, d)
+    v_flat = v_sel.transpose(1, 2, 0, 3, 4).reshape(b, hkv, n * page, d)
     qf = q.astype(jnp.float32).reshape(b, hkv, g, d) * scale
-    s_pages = jnp.einsum("bkgd,btkd->bkgt", qf,
+    s_pages = jnp.einsum("bkgd,bktd->bkgt", qf,
                          k_flat.astype(jnp.float32)).reshape(b, hq, n * page)
-    s_tail = jnp.einsum("bkgd,btkd->bkgt", qf,
+    s_tail = jnp.einsum("bkgd,bktd->bkgt", qf,
                         k_tail.astype(jnp.float32)).reshape(b, hq, page)
     s_pages = _softcap(s_pages, logit_cap)
     s_tail = _softcap(s_tail, logit_cap)
@@ -114,9 +128,9 @@ def paged_decode_attention_ref(
     s_tail = jnp.where(t_mask[None, None, :], s_tail, NEG_INF)
     s = jnp.concatenate([s_pages, s_tail], axis=-1)
     p = jax.nn.softmax(s, axis=-1)
-    v_all = jnp.concatenate([v_flat, v_tail], axis=1)   # (B, T, Hkv, D)
+    v_all = jnp.concatenate([v_flat, v_tail], axis=2)   # (B, Hkv, T, D)
     pf = p.reshape(b, hkv, g, -1)
-    out = jnp.einsum("bkgt,btkd->bkgd", pf, v_all.astype(jnp.float32))
+    out = jnp.einsum("bkgt,bktd->bkgd", pf, v_all.astype(jnp.float32))
     return out.reshape(b, hq, d).astype(q.dtype)
 
 

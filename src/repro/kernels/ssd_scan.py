@@ -71,7 +71,7 @@ def ssd_scan_pallas(
     c_mat: jax.Array,  # (B, S, H, N)
     chunk: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ):
     bsz, s, h, p = x.shape
     n = b_mat.shape[-1]

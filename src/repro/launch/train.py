@@ -23,6 +23,7 @@ from repro.api import HyperOffloadSession, OffloadConfig
 from repro.checkpoint.ckpt import load_checkpoint, save_checkpoint
 from repro.configs import REGISTRY
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.optim.adamw import AdamWState
 
@@ -43,6 +44,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = REGISTRY[args.arch]
     if args.smoke:

@@ -60,11 +60,6 @@ def moe_ffn(cfg: ModelConfig, p: Dict, x: jax.Array) -> Tuple[jax.Array, jax.Arr
 
 def _moe_ffn_sharded(cfg: ModelConfig, p: Dict, x: jax.Array, mesh, rules):
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # older JAX
-        from jax.experimental.shard_map import shard_map as _sm
-        shard_map = _sm
 
     dp = rules.get("batch") or ()
     dp = (dp,) if isinstance(dp, str) else tuple(dp)
@@ -82,7 +77,7 @@ def _moe_ffn_sharded(cfg: ModelConfig, p: Dict, x: jax.Array, mesh, rules):
             aux = jax.lax.pmean(aux, dp)
         return out, aux
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp or None), P(), P(None, None, tp or None),
                   P(None, None, tp or None), P(None, tp or None, None)),

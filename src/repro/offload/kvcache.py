@@ -4,7 +4,9 @@ Layout per layer: each full page is its own entry in the
 ``MemoryPoolManager`` (host tier — pages are non-contiguous by
 construction, exactly like a paged allocator); the device keeps (a) a small
 *tail* buffer accumulating the current partial page and (b) per-page key
-*summaries* (mean key per page) used for sparse block selection — the
+*summaries* (mean key per page) used for sparse block selection. Pages and
+tail are head-major, ``(B, Hkv, page, D)``: the layout the paged-decode
+kernel tiles, so no path transposes a page after it is written. This is the
 paper's DeepSeek+NSA inference setting, where only the top-k relevant KV
 blocks are reloaded per decode step instead of the whole cache.
 
@@ -16,9 +18,8 @@ The page fetch is the Prefetch cache operator (sync via ``pool.get`` or
 async via ``prefetch_pages``/``TransferEngine``, which is how the serving
 engine overlaps next-layer fetches with the current layer's compute); the
 page flush on tail overflow is the Store. Capacity accounting and
-host-kind probing live in the pool — on platforms where ``pinned_host``
-shardings raise, pages degrade to ``unpinned_host`` or NumPy host buffers
-without the cache noticing.
+host-kind probing live in the pool (``pool.backend``), so the cache never
+sees which host memory kind holds its pages.
 """
 
 from __future__ import annotations
@@ -32,10 +33,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.ref import paged_decode_attention_ref
+from repro.kernels.ref import (
+    merged_pages_attention, paged_decode_attention_ref,
+)
 from repro.pool import MemoryPoolManager, TransferHandle, auto_depth
-
-NEG_INF = -2.3819763e38
 
 #: jitted exact-math fused attend (the lowering-free serving path);
 #: retraces only when the page-table *length* changes — once per flushed
@@ -43,6 +44,9 @@ NEG_INF = -2.3819763e38
 _fused_attend_ref = functools.partial(
     jax.jit, static_argnames=("scale", "logit_cap"))(
         paged_decode_attention_ref)
+#: the gather path's attend over already-fetched pages: the same math
+_paged_attend = functools.partial(
+    jax.jit, static_argnames=("scale", "logit_cap"))(merged_pages_attention)
 
 # per-instance pool-key namespace, so caches sharing one pool (e.g. one pool
 # across a model's layers) never collide on page keys
@@ -119,8 +123,8 @@ def worst_case_page_bytes(cache_specs) -> int:
 
 @jax.jit
 def _page_summary(k_page: jax.Array) -> jax.Array:
-    """(B, page, Hkv, D) -> (B, Hkv, D) mean key."""
-    return jnp.mean(k_page, axis=1)
+    """(B, Hkv, page, D) -> (B, Hkv, D) mean key."""
+    return jnp.mean(k_page, axis=2)
 
 
 @dataclasses.dataclass
@@ -158,7 +162,7 @@ class PagedKVCache:
     k_pool: List[Optional[str]]   # per page: pool key of the K page, or None
     v_pool: List[Optional[str]]
     k_summary: jax.Array       # (n_pages, B, Hkv, D) — device
-    k_tail: jax.Array          # (B, page, Hkv, D) — device (partial page)
+    k_tail: jax.Array          # (B, Hkv, page, D) — device (partial page)
     v_tail: jax.Array
     length: int = 0            # tokens appended so far
     fetches: int = 0           # pool→device page transfers (stats)
@@ -173,7 +177,7 @@ class PagedKVCache:
     use_kernel: bool = False             # Pallas kernel vs exact jnp ref
     buffer_hits: int = 0
     buffer_misses: int = 0
-    _kbuf: Optional[jax.Array] = None    # (n_slots, B, page, Hkv, D)
+    _kbuf: Optional[jax.Array] = None    # (n_slots, B, Hkv, page, D)
     _vbuf: Optional[jax.Array] = None
     _slot_of: Dict[int, int] = dataclasses.field(default_factory=dict)
     _slot_page: List[Optional[int]] = dataclasses.field(default_factory=list)
@@ -202,8 +206,8 @@ class PagedKVCache:
             pool=pool,
             k_pool=[None] * n_pages, v_pool=[None] * n_pages,
             k_summary=jnp.zeros((n_pages, batch, n_kv_heads, head_dim), dtype),
-            k_tail=jnp.zeros((batch, page_size, n_kv_heads, head_dim), dtype),
-            v_tail=jnp.zeros((batch, page_size, n_kv_heads, head_dim), dtype),
+            k_tail=jnp.zeros((batch, n_kv_heads, page_size, head_dim), dtype),
+            v_tail=jnp.zeros((batch, n_kv_heads, page_size, head_dim), dtype),
             key_ns=f"kvcache{next(_CACHE_IDS)}",
             device_pages=device_pages, use_kernel=use_kernel,
         )
@@ -250,28 +254,29 @@ class PagedKVCache:
     def append(self, k_t: jax.Array, v_t: jax.Array) -> None:
         """Append one token's K/V: (B, Hkv, D)."""
         i = self.tail_len
-        self.k_tail = self.k_tail.at[:, i].set(k_t.astype(self.dtype))
-        self.v_tail = self.v_tail.at[:, i].set(v_t.astype(self.dtype))
+        self.k_tail = self.k_tail.at[:, :, i].set(k_t.astype(self.dtype))
+        self.v_tail = self.v_tail.at[:, :, i].set(v_t.astype(self.dtype))
         self.length += 1
         if self.length % self.page_size == 0:
             self._flush_tail()
 
     def prefill(self, k_seq: jax.Array, v_seq: jax.Array) -> None:
-        """Bulk-append a prompt: (B, S, Hkv, D)."""
+        """Bulk-append a prompt: (B, S, Hkv, D), the model's layout."""
         s = k_seq.shape[1]
+        k_seq = k_seq.transpose(0, 2, 1, 3).astype(self.dtype)
+        v_seq = v_seq.transpose(0, 2, 1, 3).astype(self.dtype)
         n_full = s // self.page_size
         for pi in range(n_full):
             sl = slice(pi * self.page_size, (pi + 1) * self.page_size)
-            kp = k_seq[:, sl].astype(self.dtype)
-            vp = v_seq[:, sl].astype(self.dtype)
+            kp, vp = k_seq[:, :, sl], v_seq[:, :, sl]
             self._store_page(pi, kp, vp)
             self.k_summary = self.k_summary.at[pi].set(_page_summary(kp))
         rem = s - n_full * self.page_size
         if rem:
-            self.k_tail = self.k_tail.at[:, :rem].set(
-                k_seq[:, n_full * self.page_size:].astype(self.dtype))
-            self.v_tail = self.v_tail.at[:, :rem].set(
-                v_seq[:, n_full * self.page_size:].astype(self.dtype))
+            self.k_tail = self.k_tail.at[:, :, :rem].set(
+                k_seq[:, :, n_full * self.page_size:])
+            self.v_tail = self.v_tail.at[:, :, :rem].set(
+                v_seq[:, :, n_full * self.page_size:])
         self.length = s
 
     # ------------------------------------------------------------------
@@ -290,11 +295,11 @@ class PagedKVCache:
         return np.sort(idx)
 
     def _page_shape(self) -> Tuple[int, ...]:
-        return (self.batch, self.page_size, self.n_kv_heads, self.head_dim)
+        return (self.batch, self.n_kv_heads, self.page_size, self.head_dim)
 
     def fetch_pages(self, idx: Sequence[int]) -> Tuple[jax.Array, jax.Array]:
         """Prefetch (sync): copy the selected pool pages to device memory.
-        Returns (n_sel, B, page, Hkv, D) device arrays."""
+        Returns (n_sel, B, Hkv, page, D) device arrays."""
         if len(idx) == 0:
             shape = (0,) + self._page_shape()
             return jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype)
@@ -329,7 +334,7 @@ class PagedKVCache:
             idx = self.select_pages(q, top_k_pages)
             kp, vp = self.fetch_pages(idx)
         return _paged_attend(q, kp, vp, self.k_tail, self.v_tail,
-                             jnp.int32(self.tail_len), scale)
+                             jnp.int32(self.tail_len), scale=scale)
 
     # -- fused decode over the device page buffer ----------------------
     @property
@@ -416,45 +421,12 @@ class PagedKVCache:
         interpret mode on CPU)."""
         idx = self.select_pages(q, top_k_pages)
         slots = self._ensure_resident(idx)
-        table = jnp.asarray(slots, jnp.int32)
+        args = (q, self._kbuf, self._vbuf, jnp.asarray(slots, jnp.int32),
+                self.k_tail, self.v_tail, jnp.int32(self.tail_len))
         if use_kernel is None:
             use_kernel = self.use_kernel
         if use_kernel:
             from repro.kernels.ops import paged_decode_attention
-            return paged_decode_attention(
-                q, self._kbuf, self._vbuf, table, self.k_tail, self.v_tail,
-                jnp.int32(self.tail_len), scale=scale)
-        return _fused_attend_ref(q, self._kbuf, self._vbuf, table,
-                                 self.k_tail, self.v_tail,
-                                 jnp.int32(self.tail_len), scale=scale)
+            return paged_decode_attention(*args, scale=scale)
+        return _fused_attend_ref(*args, scale=scale)
 
-
-@jax.jit
-def _segment_scores(q, k, scale):
-    """q (B,Hq,D), k (B,T,Hkv,D) -> scores (B,Hq,T) in f32 (GQA aware)."""
-    b, hq, d = q.shape
-    hkv = k.shape[2]
-    g = hq // hkv
-    qf = q.astype(jnp.float32).reshape(b, hkv, g, d) * scale
-    return jnp.einsum("bkgd,btkd->bkgt", qf, k.astype(jnp.float32)).reshape(
-        b, hq, k.shape[1])
-
-
-@jax.jit
-def _paged_attend(q, k_pages, v_pages, k_tail, v_tail, tail_len, scale):
-    """Exact attention over [pages ++ tail] in one merged softmax."""
-    b, hq, d = q.shape
-    n, _, page, hkv, _ = k_pages.shape
-    k_flat = k_pages.transpose(1, 0, 2, 3, 4).reshape(b, n * page, hkv, d)
-    v_flat = v_pages.transpose(1, 0, 2, 3, 4).reshape(b, n * page, hkv, d)
-    s_pages = _segment_scores(q, k_flat, scale)              # (B,Hq,n*page)
-    s_tail = _segment_scores(q, k_tail, scale)               # (B,Hq,page)
-    t_mask = jnp.arange(k_tail.shape[1]) < tail_len
-    s_tail = jnp.where(t_mask[None, None, :], s_tail, NEG_INF)
-    s = jnp.concatenate([s_pages, s_tail], axis=-1)
-    p = jax.nn.softmax(s, axis=-1)
-    v_all = jnp.concatenate([v_flat, v_tail], axis=1)        # (B,T,Hkv,D)
-    g = hq // hkv
-    pf = p.reshape(b, hkv, g, -1)
-    out = jnp.einsum("bkgt,btkd->bkgd", pf, v_all.astype(jnp.float32))
-    return out.reshape(b, hq, d).astype(q.dtype)

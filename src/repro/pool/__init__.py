@@ -5,7 +5,7 @@
   modeled latency/bandwidth) instead of hard-coded tier strings;
 - ``backend``  — tiered memory backends (device HBM / host memory-kind
   shardings / sleep-throttled modeled disaggregated tier) behind one
-  interface, with per-device capability probing and graceful fallback;
+  interface, with per-device capability probing;
 - ``codec``    — int8/fp8 KV page codecs (per-page absmax scales); tiers
   below a configurable boundary store and move encoded payloads, so
   host/remote transfers carry 2–4× fewer bytes;
@@ -26,7 +26,7 @@ from repro.pool.backend import (
 )
 from repro.pool.codec import (
     CODECS, EncodedPage, Fp8Codec, Int8Codec, KVCodec, make_codec,
-    numpy_supports_fp8, roundtrip_bound,
+    roundtrip_bound,
 )
 from repro.pool.topology import TierSpec, TierTopology, sweep_topologies
 from repro.pool.manager import (
@@ -40,7 +40,7 @@ from repro.pool.executor import ExecutionTrace, OffloadPlanExecutor
 __all__ = [
     "DEVICE_TIER", "HOST_TIER", "REMOTE_TIER",
     "CODECS", "CodecBackend", "EncodedPage", "Fp8Codec", "Int8Codec",
-    "KVCodec", "make_codec", "numpy_supports_fp8", "roundtrip_bound",
+    "KVCodec", "make_codec", "roundtrip_bound",
     "ModeledTierBackend", "backend_for",
     "capabilities", "device_sharding", "host_memory_kind", "host_sharding",
     "is_host_resident", "make_backend", "make_host_backend",

@@ -4,10 +4,11 @@ Backends are selected per-tier by a declarative ``TierSpec.kind``
 (``pool.topology``); the default chain mirrors the paper's hierarchy:
 
 - **device** — accelerator HBM (JAX default memory);
-- **host**   — ``pinned_host`` memory-kind shardings where the platform
-  supports them (TPU/GPU), degrading to ``unpinned_host`` and finally to
-  plain NumPy host buffers where memory-kind shardings raise (XLA:CPU only
-  addresses ``unpinned_host``; some builds address nothing but the default);
+- **host**   — ``pinned_host`` memory-kind shardings, else
+  ``unpinned_host``. An accelerator that addresses neither cannot keep
+  the tier in its host memory, and building the tier there raises; only a
+  CPU build that addresses no host kind falls back to plain NumPy
+  buffers;
 - **modeled** — the disaggregated pooled-DRAM stand-in (CloudMatrix /
   CXL-hybrid tier): NumPy storage behind a sleep-throttle that *enforces*
   the spec's per-direction bandwidth and latency, so the runtime feels —
@@ -47,7 +48,7 @@ class Capabilities:
     """What one device can address, probed once."""
 
     platform: str
-    memory_kinds: Tuple[str, ...]      # addressable kinds ("" if unknown)
+    memory_kinds: Tuple[str, ...]      # addressable kinds
     default_kind: Optional[str]        # the device's default memory kind
     host_kind: Optional[str]           # best host kind, None → NumPy fallback
 
@@ -57,23 +58,11 @@ class Capabilities:
 
 
 def _probe(device) -> Capabilities:
-    kinds: Tuple[str, ...] = ()
-    default = None
-    try:
-        kinds = tuple(m.kind for m in device.addressable_memories())
-        default = device.default_memory().kind
-    except Exception:  # very old jaxlib: no memories API
-        pass
+    kinds = tuple(m.kind for m in device.addressable_memories())
     host = next((k for k in _HOST_KIND_PREFERENCE if k in kinds), None)
-    if host is not None:
-        # the kind being listed is not enough on every build — a put must work
-        try:
-            s = jax.sharding.SingleDeviceSharding(device, memory_kind=host)
-            jax.device_put(np.zeros(1, np.uint8), s)
-        except Exception:
-            host = None
     return Capabilities(platform=device.platform, memory_kinds=kinds,
-                        default_kind=default, host_kind=host)
+                        default_kind=device.default_memory().kind,
+                        host_kind=host)
 
 
 @functools.lru_cache(maxsize=None)
@@ -353,9 +342,18 @@ class CodecBackend(MemoryBackend):
 
 
 def make_host_backend(device=None) -> MemoryBackend:
-    """The best host-tier backend this platform supports."""
-    if host_memory_kind(device) is not None:
+    """The best host-tier backend this platform supports: a memory-kind
+    backend, or NumPy buffers on a CPU build that addresses no host kind.
+    An accelerator without a host kind raises instead of hiding the host
+    tier off its host memory."""
+    caps = capabilities(device)
+    if caps.host_kind is not None:
         return JaxHostBackend(device)
+    if caps.platform != "cpu":
+        raise RuntimeError(
+            f"{caps.platform} device addresses no host memory kind "
+            f"(has {caps.memory_kinds}); the host tier cannot live in its "
+            "host memory")
     return NumpyHostBackend(device)
 
 

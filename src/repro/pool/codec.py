@@ -37,7 +37,6 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 CODECS = ("none", "int8", "fp8")
 
@@ -165,14 +164,3 @@ def roundtrip_bound(codec: KVCodec, absmax: float) -> float:
     if codec.name == "fp8":
         return absmax / 16.0 + 1e-7
     raise ValueError(f"no round-trip bound for codec {codec.name!r}")
-
-
-def numpy_supports_fp8() -> bool:
-    """ml_dtypes-backed NumPy float8 support (jax always ships ml_dtypes,
-    but probe anyway so a missing build degrades loudly at config time
-    instead of deep inside a spill)."""
-    try:
-        np.zeros(1, dtype=jnp.float8_e4m3fn)
-        return True
-    except Exception:
-        return False

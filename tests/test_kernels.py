@@ -34,7 +34,8 @@ def test_flash_attention_shapes_dtypes(b, hq, hkv, s, d, dtype):
     q = jax.random.normal(ks[0], (b, hq, s, d)).astype(dtype)
     k = jax.random.normal(ks[1], (b, hkv, s, d)).astype(dtype)
     v = jax.random.normal(ks[2], (b, hkv, s, d)).astype(dtype)
-    out = flash_attention_pallas(q, k, v, scale=d ** -0.5, block_q=32, block_k=32)
+    out = flash_attention_pallas(q, k, v, scale=d ** -0.5, block_q=32, block_k=32,
+                                 interpret=True)
     ref = flash_attention_ref(q, k, v, scale=d ** -0.5)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -56,7 +57,7 @@ def test_flash_attention_flags(window, cap, causal):
     v = jax.random.normal(ks[2], (b, hkv, s, d))
     out = flash_attention_pallas(q, k, v, scale=0.2, causal=causal,
                                  window=window, logit_cap=cap,
-                                 block_q=16, block_k=16)
+                                 block_q=16, block_k=16, interpret=True)
     ref = flash_attention_ref(q, k, v, scale=0.2, causal=causal,
                               window=window, logit_cap=cap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
@@ -75,7 +76,7 @@ def test_decode_attention(b, hq, hkv, c, d, pos):
     k = jax.random.normal(ks[1], (b, hkv, c, d))
     v = jax.random.normal(ks[2], (b, hkv, c, d))
     out = decode_attention_pallas(q, k, v, jnp.int32(pos), scale=d ** -0.5,
-                                  block_k=32)
+                                  block_k=32, interpret=True)
     ref = decode_attention_ref(q, k, v, jnp.int32(pos), scale=d ** -0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -88,10 +89,10 @@ def test_decode_attention(b, hq, hkv, c, d, pos):
 def _paged_inputs(key, b, hq, hkv, d, page, n_pool):
     ks = jax.random.split(jax.random.key(key), 5)
     q = jax.random.normal(ks[0], (b, hq, d))
-    kp = jax.random.normal(ks[1], (n_pool, b, page, hkv, d))
-    vp = jax.random.normal(ks[2], (n_pool, b, page, hkv, d))
-    kt = jax.random.normal(ks[3], (b, page, hkv, d))
-    vt = jax.random.normal(ks[4], (b, page, hkv, d))
+    kp = jax.random.normal(ks[1], (n_pool, b, hkv, page, d))
+    vp = jax.random.normal(ks[2], (n_pool, b, hkv, page, d))
+    kt = jax.random.normal(ks[3], (b, hkv, page, d))
+    vt = jax.random.normal(ks[4], (b, hkv, page, d))
     return q, kp, vp, kt, vt
 
 
@@ -102,7 +103,8 @@ def test_paged_decode_gqa_and_softcap(hq, hkv, cap):
     q, kp, vp, kt, vt = _paged_inputs(10, b, hq, hkv, d, page, 5)
     table = jnp.asarray([3, 0, 4], jnp.int32)     # scrambled, non-contiguous
     args = (q, kp, vp, table, kt, vt, jnp.int32(5))
-    out = paged_decode_attention_pallas(*args, scale=d ** -0.5, logit_cap=cap)
+    out = paged_decode_attention_pallas(*args, scale=d ** -0.5, logit_cap=cap,
+                                        interpret=True)
     ref = paged_decode_attention_ref(*args, scale=d ** -0.5, logit_cap=cap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -122,7 +124,8 @@ def test_paged_decode_tail_boundaries(table, tail_len):
     q, kp, vp, kt, vt = _paged_inputs(11, b, hq, hkv, d, page, 5)
     args = (q, kp, vp, jnp.asarray(table, jnp.int32), kt, vt,
             jnp.int32(tail_len))
-    out = paged_decode_attention_pallas(*args, scale=d ** -0.5)
+    out = paged_decode_attention_pallas(*args, scale=d ** -0.5,
+                                        interpret=True)
     ref = paged_decode_attention_ref(*args, scale=d ** -0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -138,7 +141,7 @@ def test_paged_decode_ref_is_bitwise_the_gather_path():
         ref = paged_decode_attention_ref(q, kp, vp, t, kt, vt,
                                          jnp.int32(tl), scale=d ** -0.5)
         gather = _paged_attend(q, kp[t], vp[t], kt, vt, jnp.int32(tl),
-                               d ** -0.5)
+                               scale=d ** -0.5)
         assert bool(jnp.all(ref == gather))
 
 
@@ -153,7 +156,7 @@ def test_decode_attention_ring_wrap_mid_block(pos):
     k = jax.random.normal(ks[1], (b, hkv, c, d))
     v = jax.random.normal(ks[2], (b, hkv, c, d))
     out = decode_attention_pallas(q, k, v, jnp.int32(pos), scale=d ** -0.5,
-                                  block_k=32)
+                                  block_k=32, interpret=True)
     ref = decode_attention_ref(q, k, v, jnp.int32(pos), scale=d ** -0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 

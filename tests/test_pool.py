@@ -54,6 +54,21 @@ def test_numpy_backend_is_always_available():
     np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
 
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_host_tier_without_host_kind(monkeypatch, platform):
+    """Only a CPU build that addresses no host memory kind may fall back
+    to NumPy host buffers; an accelerator raises instead of hiding the
+    host tier off its host memory."""
+    caps = B.Capabilities(platform=platform, memory_kinds=("device",),
+                          default_kind="device", host_kind=None)
+    monkeypatch.setattr(B, "capabilities", lambda device=None: caps)
+    if platform == "cpu":
+        assert isinstance(B.make_host_backend(), B.NumpyHostBackend)
+    else:
+        with pytest.raises(RuntimeError, match="no host memory kind"):
+            B.make_host_backend()
+
+
 def test_to_host_to_device_helpers():
     x = jnp.ones((4, 4), jnp.bfloat16)
     parked = B.to_host(x)
@@ -188,8 +203,9 @@ def test_shared_pool_across_caches_does_not_collide():
     c2.prefill(ones * 7.0, ones * 7.0)
     k1, _ = c1.fetch_pages([0])
     k2, _ = c2.fetch_pages([0])
-    np.testing.assert_array_equal(np.asarray(k1), np.asarray(ones)[None])
-    np.testing.assert_array_equal(np.asarray(k2), np.asarray(ones * 7.0)[None])
+    page0 = np.ones((1, b, hkv, page, d))     # pages are head-major
+    np.testing.assert_array_equal(np.asarray(k1), page0)
+    np.testing.assert_array_equal(np.asarray(k2), page0 * 7.0)
 
 
 # ---------------------------------------------------------------------------
