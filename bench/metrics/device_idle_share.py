@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device,
+in percent: 1 - (union of device op intervals) / window
+(``bench/trace_reduce.py``)."""
+
+
+def read(ctx):
+    return None if ctx.trace is None else 100.0 * ctx.trace.idle_share

@@ -1,0 +1,138 @@
+"""The harness on the CPU at a tiny size: it refuses the CPU as a device,
+finds cells and metrics by name, and its comparison with the reference
+passes the program and fails a broken one and the control."""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import conftest
+
+SEED = 2 ** 31 + 4242
+#: the benchmark's cells, and the cell held for later (conftest)
+CELLS = [w["name"] for w in json.loads(
+    (conftest.ROOT / "BENCHMARK.json").read_text())["workloads"]]
+CELLS += [conftest.HELD_CELL["name"]] * (conftest.HELD_CELL["name"]
+                                         not in CELLS)
+
+
+def _run(run, root, cell, trace=0, seconds=2.0, seed=SEED):
+    args = run.parse(["--workload", cell, "--seed", str(seed),
+                      "--seconds", str(seconds), "--trace", str(trace)])
+    return run.run(args, root=root)
+
+
+def test_command_refuses_the_cpu(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, str(conftest.BENCH / "run.py"), "--workload",
+         CELLS[0], "--seed", "1", "--seconds", "1",
+         "--trace", "0"], cwd=tmp_path, env=env, capture_output=True,
+        text=True, timeout=300)
+    assert p.returncode != 0
+    assert "needs 1 TPU" in p.stderr
+    assert "{" not in p.stdout
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_program_matches_reference(tiny_root, on_cpu, cell):
+    res = _run(on_cpu, tiny_root, cell)
+    assert res["correct"], res["checks"]
+    assert set(res["checks"]) >= {"mean_logit_gap"}
+    assert set(res["metrics"]) >= {"output_tok_s"}
+    assert list(res)[-1] == "checks"
+
+
+@pytest.mark.parametrize("cell,mode", [
+    ("granite-moe-offload-decode", {"mode": "resident"}),
+    ("phi3-resident-chat", {"mode": "kv_offload", "tier_rows": [2, 8]})])
+def test_other_serving_mode_matches_reference(tiny_root, on_cpu, cell, mode):
+    """Each family through the serving mode its cell does not take."""
+    path = tiny_root / "bench" / "workloads" / f"{cell}.json"
+    wl = json.loads(path.read_text())
+    wl["serving"].update(mode)
+    path.write_text(json.dumps(wl))
+    assert _run(on_cpu, tiny_root, cell)["correct"]
+
+
+def test_traced_run_reports_per_layer_metrics(tiny_root, on_cpu):
+    res = _run(on_cpu, tiny_root, "granite-moe-offload-decode", trace=1)
+    assert res["correct"]
+    assert {"offload_host_ms_per_step", "decode_host_ms_per_step",
+            "host_bytes_per_token"} <= set(res["metrics"])
+    assert "output_tok_s" not in res["metrics"]
+    # the CPU's profile has no TPU plane: the metrics that divide by the
+    # device time of the model's programs find nothing and are left out
+    assert not {"step_mfu", "decode_hbm_share",
+                "device_idle_share"} & set(res["metrics"])
+
+
+def test_discovers_added_cell_and_metric_without_edits(tiny_root, on_cpu):
+    bench = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    cell = dict(bench["workloads"][0], name="throwaway-cell")
+    bench["workloads"].append(cell)
+    bench["per_layer"].append({
+        "name": "throwaway_steps", "unit": "steps", "better": "higher",
+        "source": "host_clock", "layer": "scheduler",
+        "moves": "output_tok_s", "workloads": ["throwaway-cell"]})
+    (tiny_root / "BENCHMARK.json").write_text(json.dumps(bench))
+    wl_dir = tiny_root / "bench" / "workloads"
+    (wl_dir / "throwaway-cell.json").write_text(
+        (wl_dir / f"{bench['workloads'][0]['name']}.json").read_text())
+    (tiny_root / "bench" / "metrics" / "throwaway_steps.py").write_text(
+        "def read(ctx):\n    return float(len(ctx.steps))\n")
+    res = _run(on_cpu, tiny_root, "throwaway-cell", trace=1)
+    assert res["metrics"]["throwaway_steps"]["value"] > 0
+
+
+def _break_decode(monkeypatch, wrap):
+    from repro.sched import scheduler
+    real = scheduler.jit_decode
+
+    def broken(model):
+        return wrap(real(model))
+    monkeypatch.setattr(scheduler, "jit_decode", broken)
+
+
+def test_altered_token_is_not_correct(tiny_root, on_cpu, monkeypatch):
+    """A token altered where it is produced: the decode step's logits
+    rolled by one vocabulary entry, so argmax picks a neighbour."""
+    _break_decode(monkeypatch, lambda f: lambda *a: (
+        lambda out: (jnp.roll(out[0], 1, axis=-1), out[1]))(f(*a)))
+    assert not _run(on_cpu, tiny_root, "phi3-resident-chat")["correct"]
+
+
+def test_step_that_keeps_its_state_is_not_correct(tiny_root, on_cpu,
+                                                  monkeypatch):
+    """A decode step that returns its cache unchanged: the new token's
+    keys and values are never written."""
+    def keep_state(f):
+        def step(params, cache, tok, pos):
+            logits, _ = f(params, jax.tree.map(jnp.copy, cache), tok, pos)
+            return logits, cache
+        return step
+    _break_decode(monkeypatch, keep_state)
+    assert not _run(on_cpu, tiny_root,
+                    "granite-moe-offload-decode")["correct"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_is_not_correct(tiny_root, on_cpu, cell):
+    """The float8 control at the same positions reads past the limit."""
+    run = on_cpu
+    c = run.load_cell(tiny_root, cell)
+    served = run.serve(c, SEED, 2.0, False, jax.devices()[0],
+                       run.CompileClock())
+    picked = run.sample(served.outputs, served.longest, SEED)
+    gaps = run.logit_gaps(c.config, SEED, served.outputs, picked,
+                          chunk=c.workload["serving"]["chunk_size"],
+                          control=True)
+    for name, limit in c.workload["limits"].items():
+        assert gaps[name] <= limit < gaps[f"control_{name}"]
