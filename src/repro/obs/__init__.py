@@ -1,8 +1,9 @@
 """Unified telemetry subsystem: tracing, metrics, overlap analysis.
 
 - ``trace``   — `Tracer`: structured spans/instants in a bounded ring,
-  exported as Chrome trace-event / Perfetto JSON; `NullTracer` makes
-  disabled telemetry a no-op (``NULL_TRACER`` is the shared instance);
+  exported as Chrome trace-event / Perfetto JSON, each span also a
+  ``jax.profiler`` annotation; `NullTracer` makes disabled telemetry a
+  no-op (``NULL_TRACER`` is the shared instance);
 - ``metrics`` — `MetricsRegistry`: counters, gauges, fixed-bucket
   histograms, plus named collectors that re-home the existing subsystem
   stats snapshots; Prometheus-style text exposition;
@@ -20,13 +21,13 @@ without one.
 """
 
 from repro.obs.metrics import (
-    Counter, Gauge, Histogram, MetricsRegistry, STEP_BUCKETS,
+    SECONDS_BUCKETS, Counter, Gauge, Histogram, MetricsRegistry,
 )
 from repro.obs.overlap import OverlapAnalyzer
 from repro.obs.trace import NULL_TRACER, NullTracer, TraceEvent, Tracer
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "STEP_BUCKETS",
+    "Counter", "Gauge", "Histogram", "MetricsRegistry", "SECONDS_BUCKETS",
     "OverlapAnalyzer",
     "NULL_TRACER", "NullTracer", "TraceEvent", "Tracer",
 ]

@@ -3,7 +3,8 @@
 Validates that an exported trace is (a) well-formed Chrome trace-event
 JSON that Perfetto will open, and (b) consistent with the repo's span
 schema: every complete span has a non-negative duration (end >= start),
-and every transfer handle's events are ordered execution-start <=
+a scheduler ``step`` span names its step and a ``request`` span its
+request, and every transfer handle's events are ordered execution-start <=
 complete <= wait-resolution. (The transfer span covers execution only —
 queue time shows up as ``transfer.backpressure`` — so a blocked wait may
 legitimately *start* before its transfer span does; wait-start ordering
@@ -26,6 +27,8 @@ from repro.obs.overlap import (
 
 __all__ = ["validate_events", "validate_file"]
 
+#: category of the per-request ``request.queue/prefill/decode`` spans
+REQUEST_CAT = "request"
 _PHASES = {"X", "i", "M"}
 #: float slop for cross-thread perf_counter comparisons (microseconds)
 _EPS_US = 50.0
@@ -89,6 +92,8 @@ def validate_events(obj: Any) -> List[str]:
             if (ev.get("cat") == SCHED_CAT and ev["name"] == STEP_SPAN
                     and "step" not in args):
                 errors.append(f"{where}: sched step span missing args.step")
+            if ev.get("cat") == REQUEST_CAT and "req" not in args:
+                errors.append(f"{where}: request span missing args.req")
     # per-handle ordering: execution-start <= complete (span dur >= 0,
     # checked) and the wait resolves no earlier than the transfer
     # completes — a blocked wait ends at completion, an overlapped wait

@@ -5,7 +5,7 @@ surface hangs off. Two kinds of metric live here:
 
 - **owned instruments** — `Counter` / `Gauge` / `Histogram` objects created
   through the registry (the scheduler's per-request TTFT / queue-wait /
-  per-output-token histograms);
+  per-output-token histograms, in wall-clock seconds);
 - **collectors** — named callables returning a stats mapping, registered by
   the session for every subsystem snapshot that already exists
   (``PoolStats``/``TransferStats``/``SchedStats``/``ServeStats``/prefix
@@ -24,10 +24,14 @@ import bisect
 import re
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry"]
+__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
+           "SECONDS_BUCKETS"]
 
-#: default histogram buckets for scheduler-step latencies (virtual steps)
-STEP_BUCKETS: Tuple[float, ...] = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
+#: histogram buckets for request latencies on the wall clock, in seconds:
+#: a millisecond per output token up to a long queue before the first one
+SECONDS_BUCKETS: Tuple[float, ...] = (
+    0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
+    10.0, 30.0)
 
 
 class Counter:

@@ -14,8 +14,15 @@ Event kinds mirror the trace-event format:
 - **complete** (``ph="X"``) — a span with an explicit start + duration;
   the instrumented sites emit these at span *end*, so an event's presence
   implies the work finished;
-- **instant** (``ph="i"``) — a point event (request state transitions,
-  spill cascade hops, prefix lookups).
+- **instant** (``ph="i"``) — a point event (preemption, resumption and
+  shedding of a request, spill cascade hops, prefix lookups).
+
+While enabled, every ``span`` also enters a ``jax.profiler.TraceAnnotation``
+of the same name (``step_span`` a ``StepTraceAnnotation`` with the step
+number), so a ``jax.profiler`` capture of the process carries the
+program's phases on its host plane, on the profiler's clock, beside the
+device ops. JAX is imported the first time a span opens, so this module
+imports without it.
 
 `NullTracer` is the disabled implementation: every method is a no-op and
 ``enabled`` is False so hot paths can skip building args dicts entirely —
@@ -33,6 +40,17 @@ from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional
 
 __all__ = ["TraceEvent", "Tracer", "NullTracer", "NULL_TRACER"]
+
+_PROFILER: Any = None
+
+
+def _profiler() -> Any:
+    """``jax.profiler``, imported once, on the first span."""
+    global _PROFILER
+    if _PROFILER is None:
+        import jax.profiler
+        _PROFILER = jax.profiler
+    return _PROFILER
 
 
 class TraceEvent:
@@ -94,13 +112,26 @@ class Tracer:
                               self.now() if ts is None else ts,
                               0.0, threading.get_ident(), args))
 
+    def span(self, cat: str, name: str, **args: Any):
+        return self._span(_profiler().TraceAnnotation(name), cat, name, args)
+
+    def step_span(self, cat: str, name: str, step: int, **args: Any):
+        """A ``span`` carrying ``args.step``, annotated for the profiler as
+        step ``step`` (``StepTraceAnnotation``)."""
+        args["step"] = step
+        return self._span(
+            _profiler().StepTraceAnnotation(name, step_num=step),
+            cat, name, args)
+
     @contextmanager
-    def span(self, cat: str, name: str, **args: Any) -> Iterator[None]:
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.complete(cat, name, t0, self.now() - t0, args or None)
+    def _span(self, annotation: Any, cat: str, name: str,
+              args: Dict[str, Any]) -> Iterator[None]:
+        with annotation:
+            t0 = self.now()
+            try:
+                yield
+            finally:
+                self.complete(cat, name, t0, self.now() - t0, args or None)
 
     def _push(self, ev: TraceEvent) -> None:
         with self._lock:
@@ -196,6 +227,10 @@ class NullTracer:
         pass
 
     def span(self, cat: str, name: str, **args: Any) -> _NullSpan:
+        return _NULL_SPAN
+
+    def step_span(self, cat: str, name: str, step: int,
+                  **args: Any) -> _NullSpan:
         return _NULL_SPAN
 
     def __len__(self) -> int:

@@ -18,6 +18,12 @@ DONE:
 - DONE     — produced ``max_new_tokens``; slot freed, reservation released,
              pages dropped.
 
+On the wall clock the spine is three phases, each a ``request.*`` span
+when the scheduler traces: ``request.queue`` (submit → slot taken),
+``request.prefill`` (→ first token sampled) and ``request.decode`` (→
+retired); a preempted request's time off its slot is a ``request.queue``
+span of its own.
+
 Two SLO-mode-only states branch off that spine:
 
 - PREEMPTED — was PREFILL or DECODE; its slot was handed to a deadline-
@@ -113,9 +119,17 @@ class RequestState:
     preemptions: int = 0               # times parked mid-flight (SLO mode)
     last_step: int = -1                # last scheduler step that decoded us
     joined_step: int = -1
-    t_joined: Optional[float] = None   # admission time (queue-wait metric)
+    # virtual clock (scheduler steps): what the SLO policy reads
     t_first_token: Optional[float] = None
     t_done: Optional[float] = None
+    # wall clock (``Tracer.now``, seconds): submit, first slot taken,
+    # first token sampled, retired; ``wall_phase`` is when the current
+    # ``request.*`` span (queue, prefill or decode) began
+    wall_submit: Optional[float] = None
+    wall_joined: Optional[float] = None
+    wall_first_token: Optional[float] = None
+    wall_done: Optional[float] = None
+    wall_phase: Optional[float] = None
 
     @property
     def req_id(self) -> int:

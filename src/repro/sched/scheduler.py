@@ -39,9 +39,15 @@ requests; each step the scheduler
    step's admission and prefill work between issue and wait, replacing the
    reactive store-then-immediately-wait round trip.
 
-Time is a virtual clock (1.0 per step) so arrival traces and latency
-measurements are deterministic; wall-clock throughput is the caller's to
-measure around ``run``.
+Time is a virtual clock (1.0 per step) so arrival traces, admission and
+the SLO policy are deterministic. Each request also carries wall-clock
+stamps (submit, first slot, first token, retire) behind the per-request
+latency histograms, in seconds. With a tracer, each of its state changes
+closes a ``request.queue``, ``request.prefill`` or ``request.decode``
+span, and inside the step's phase spans ``dispatch`` wraps each jitted
+call the step enqueues and ``device_wait`` each read that blocks the host
+on the device: a step's host work is its ``step`` span less its
+``device_wait`` spans.
 """
 
 from __future__ import annotations
@@ -57,7 +63,7 @@ import numpy as np
 from repro.core.costmodel import HardwareSpec, TPU_V5E
 from repro.core.insertion import InsertionOptions
 from repro.models.model import Model
-from repro.obs.metrics import STEP_BUCKETS, MetricsRegistry
+from repro.obs.metrics import SECONDS_BUCKETS, MetricsRegistry
 from repro.obs.trace import NULL_TRACER
 from repro.offload.kvcache import KVPageTable, worst_case_page_bytes
 from repro.pool import MemoryPoolManager, auto_depth, default_pool
@@ -143,21 +149,19 @@ class ContinuousScheduler:
         self.stats = SchedStats()
         self.finished: Dict[int, RequestState] = {}
         self._tracer = tracer if tracer is not None else NULL_TRACER
-        # per-request latency histograms (virtual scheduler steps), shared
+        # per-request latency histograms (wall-clock seconds), shared
         # across a session's schedulers via the one registry
         self._metrics = metrics
         if metrics is not None:
             self._h_ttft = metrics.histogram(
-                "req_ttft_steps", STEP_BUCKETS,
-                "request arrival to first token, scheduler steps")
+                "req_ttft_seconds", SECONDS_BUCKETS,
+                "request submit to first token, seconds")
             self._h_queue_wait = metrics.histogram(
-                "req_queue_wait_steps", STEP_BUCKETS,
-                "request arrival to admission, scheduler steps")
+                "req_queue_wait_seconds", SECONDS_BUCKETS,
+                "request submit to its first slot, seconds")
             self._h_tpot = metrics.histogram(
-                "req_time_per_output_token_steps",
-                (0.25, 0.5, 1, 2, 4, 8, 16, 32),
-                "mean per-output-token latency after the first token, "
-                "scheduler steps")
+                "req_tpot_seconds", SECONDS_BUCKETS,
+                "mean time per output token after the first, seconds")
 
         if cfg.chunk_size is not None:
             if not 1 <= cfg.chunk_size <= cfg.max_seq:
@@ -262,12 +266,9 @@ class ContinuousScheduler:
             raise ValueError(
                 f"request {request.req_id}: prompt+decode "
                 f"{request.total_len} exceeds max_seq {self.cfg.max_seq}")
-        if self._tracer.enabled:
-            self._tracer.instant("request", "QUEUED",
-                                 {"req": request.req_id,
-                                  "prompt_len": request.prompt_len,
-                                  "arrival": request.arrival})
-        return self.queue.push(request)
+        state = self.queue.push(request)
+        state.wall_submit = state.wall_phase = self._tracer.now()
+        return state
 
     @property
     def active(self) -> List[RequestState]:
@@ -302,6 +303,19 @@ class ContinuousScheduler:
     def prefix_stats(self) -> Optional[Dict[str, float]]:
         return None if self.prefix_cache is None else \
             self.prefix_cache.snapshot()
+
+    def _end_phase(self, state: RequestState, phase: str,
+                   **args: Any) -> float:
+        """End the request's current wall-clock phase with a
+        ``request.<phase>`` span (``queue``, ``prefill`` or ``decode``)
+        and start the next at the same instant, which is returned."""
+        t = self._tracer.now()
+        if self._tracer.enabled:
+            self._tracer.complete("request", f"request.{phase}",
+                                  state.wall_phase, t - state.wall_phase,
+                                  dict(args, req=state.req_id))
+        state.wall_phase = t
+        return t
 
     # -- step phases ---------------------------------------------------
     def _on_evict(self, entry: PoolEntry, dst: str) -> None:
@@ -449,6 +463,7 @@ class ContinuousScheduler:
         """Terminal drop from the queue: never admitted, so there is no
         slot, reservation, or page to release."""
         self.queue.remove(state)
+        self._end_phase(state, "queue")
         state.status = SHED
         state.t_done = self.now
         self.finished[state.req_id] = state
@@ -470,6 +485,8 @@ class ContinuousScheduler:
         capacity reservation is kept — the pages still occupy pool space,
         so admission stays exactly as conservative as before."""
         slot = victim.slot
+        self._end_phase(victim,
+                        "decode" if victim.status == DECODE else "prefill")
         if victim.status == DECODE and not self.cfg.kv_offload:
             victim.chunk_cache = jax.tree.map(
                 lambda big: big[:, slot:slot + 1], self.cache)
@@ -515,6 +532,7 @@ class ContinuousScheduler:
         PREFILL sequence just re-enters the chunked loop, which restores
         its row on its next advance."""
         was_decode = state.t_first_token is not None
+        self._end_phase(state, "queue")
         self.slots[slot] = state
         state.slot = slot
         state.status = DECODE if was_decode else PREFILL
@@ -693,9 +711,10 @@ class ContinuousScheduler:
         valid = end - start
         toks = np.zeros((1, chunk), np.int32)
         toks[0, :valid] = req.tokens[start:end]
-        logits, row = self._chunk_prefill(
-            self.params, {"tokens": jnp.asarray(toks)},
-            jnp.int32(start), jnp.int32(valid), row)
+        with self._tracer.span("sched", "dispatch"):
+            logits, row = self._chunk_prefill(
+                self.params, {"tokens": jnp.asarray(toks)},
+                jnp.int32(start), jnp.int32(valid), row)
         state.prefill_pos = end
         state.last_step = self.stats.steps
         self.stats.prefill_tokens += valid
@@ -772,14 +791,12 @@ class ContinuousScheduler:
         state.slot = slot
         self.slots[slot] = state
         state.joined_step = self.stats.steps
-        state.t_joined = self.now
+        state.wall_joined = self._end_phase(
+            state, "queue", prompt_len=state.request.prompt_len)
         if self.cfg.kv_offload:   # resident mode never parks a page
             state.pages = KVPageTable(
                 self.pool, f"{self._ns}/req{state.req_id}")
         self.stats.joins += 1
-        if self._tracer.enabled:
-            self._tracer.instant("request", "PREFILL",
-                                 {"req": state.req_id, "slot": slot})
 
     def _finish_prefill(self, state: RequestState, logits: jax.Array,
                         row: Any) -> Tuple[int, int]:
@@ -790,21 +807,24 @@ class ContinuousScheduler:
         whole-prompt and chunked paths cannot drift apart on the token-
         identity-critical sampling and state transition."""
         req = state.request
-        self.cache = jax.tree.map(
-            lambda big, r: big.at[:, state.slot].set(r[:, 0]),
-            self.cache, row)
+        tr = self._tracer
+        with tr.span("sched", "dispatch"):
+            self.cache = jax.tree.map(
+                lambda big, r: big.at[:, state.slot].set(r[:, 0]),
+                self.cache, row)
         key = state.sample_key() if req.temperature > 0.0 else None
-        tok = int(sample_token(logits[:, 0], key,
-                               temperature=req.temperature,
-                               top_k=req.top_k)[0])
+        with tr.span("sched", "device_wait"):
+            tok = int(sample_token(logits[:, 0], key,
+                                   temperature=req.temperature,
+                                   top_k=req.top_k)[0])
+        state.wall_first_token = self._end_phase(
+            state, "prefill", prompt_len=req.prompt_len)
         state.out.append(tok)
         state.last_tok = tok
         state.pos = req.prompt_len    # next decode writes here
         state.t_first_token = self.now
         state.status = DECODE
         state.last_step = self.stats.steps
-        if self._tracer.enabled:
-            self._tracer.instant("request", "DECODE", {"req": req.req_id})
         if state.done:                # max_new_tokens == 1
             self._retire(state)
         return (req.req_id, tok)
@@ -813,8 +833,10 @@ class ContinuousScheduler:
         req = state.request
         self._take_slot(state, slot)
         row = self.model.init_cache(1, self.cfg.max_seq, self.cfg.cache_dtype)
-        logits, row = self._prefill(
-            self.params, {"tokens": jnp.asarray(req.tokens[None, :])}, row)
+        with self._tracer.span("sched", "dispatch"):
+            logits, row = self._prefill(
+                self.params, {"tokens": jnp.asarray(req.tokens[None, :])},
+                row)
         self.stats.prefill_tokens += req.prompt_len
         return self._finish_prefill(state, logits, row)
 
@@ -828,21 +850,26 @@ class ContinuousScheduler:
         for s in live:
             tok[s.slot, 0] = s.last_tok
             pos[s.slot] = s.pos
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          jnp.asarray(tok), jnp.asarray(pos))
+        tr = self._tracer
+        with tr.span("sched", "dispatch"):
+            logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(tok), jnp.asarray(pos))
         emitted: List[Tuple[int, int]] = []
         greedy = None   # one batched argmax serves every temperature-0 row
         for s in live:
             req = s.request
             if req.temperature <= 0.0:
                 if greedy is None:
-                    greedy = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+                    with tr.span("sched", "device_wait"):
+                        greedy = np.asarray(
+                            jnp.argmax(logits[:, 0], axis=-1))
                 t = int(greedy[s.slot])
             else:
-                t = int(sample_token(logits[s.slot:s.slot + 1, 0],
-                                     s.sample_key(),
-                                     temperature=req.temperature,
-                                     top_k=req.top_k)[0])
+                with tr.span("sched", "device_wait"):
+                    t = int(sample_token(logits[s.slot:s.slot + 1, 0],
+                                         s.sample_key(),
+                                         temperature=req.temperature,
+                                         top_k=req.top_k)[0])
             s.out.append(t)
             s.last_tok = t
             s.pos += 1
@@ -856,18 +883,13 @@ class ContinuousScheduler:
     def _retire(self, state: RequestState) -> None:
         state.status = DONE
         state.t_done = self.now
-        arrival = state.request.arrival
+        state.wall_done = self._end_phase(state, "decode",
+                                          tokens=len(state.out))
         if self._metrics is not None:
-            self._h_ttft.observe(state.t_first_token - arrival)
-            self._h_queue_wait.observe(state.t_joined - arrival)
-            self._h_tpot.observe((state.t_done - state.t_first_token)
+            self._h_ttft.observe(state.wall_first_token - state.wall_submit)
+            self._h_queue_wait.observe(state.wall_joined - state.wall_submit)
+            self._h_tpot.observe((state.wall_done - state.wall_first_token)
                                  / max(len(state.out) - 1, 1))
-        if self._tracer.enabled:
-            self._tracer.instant("request", "DONE",
-                                 {"req": state.req_id,
-                                  "tokens": len(state.out),
-                                  "ttft_steps": state.t_first_token - arrival,
-                                  "latency_steps": state.t_done - arrival})
         if self.prefix_cache is not None:
             self._donate_prefix(state)
             if state.prefix_hit is not None:
@@ -965,7 +987,7 @@ class ContinuousScheduler:
         newly admitted slot was free when the fetches were issued, so the
         joiner's freshly scattered rows are never clobbered by collect."""
         tr = self._tracer
-        with tr.span("sched", "step", step=self.stats.steps):
+        with tr.step_span("sched", "step", self.stats.steps):
             with tr.span("sched", "admit_prefill"):
                 emitted = self._admit_and_prefill()
             if self._inflight is not None:

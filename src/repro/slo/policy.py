@@ -39,10 +39,10 @@ class SLOSpec:
 
     Deadlines are in virtual scheduler steps relative to ``arrival``:
     ``ttft_deadline`` bounds arrival → first token, ``tpot_deadline``
-    bounds the mean per-output-token latency after the first token
-    (matching the ``req_time_per_output_token_steps`` histogram). ``None``
-    means unconstrained — a request with no deadlines always counts as
-    met, so pure-throughput traffic is goodput by definition."""
+    bounds the mean per-output-token latency after the first token (the
+    quantity the ``req_tpot_seconds`` histogram takes on the wall clock).
+    ``None`` means unconstrained — a request with no deadlines always
+    counts as met, so pure-throughput traffic is goodput by definition."""
 
     priority_class: str = "standard"
     ttft_deadline: Optional[float] = None

@@ -2,9 +2,12 @@
 trace export + schema checker, metrics registry / Prometheus exposition,
 the overlap analyzer's hidden-vs-exposed decomposition and its exact
 agreement with `TransferStats`, and the front-door wiring (telemetry on:
-one shared tracer, lifecycle instants, latency histograms; telemetry off:
-zero events, `session.stats()` unchanged in shape, identical tokens)."""
+one shared tracer, contiguous wall-clock request spans, step sub-spans
+nested in their phases, latency histograms in seconds, the phases in a
+`jax.profiler` capture; telemetry off: zero events, no annotations,
+`session.stats()` unchanged in shape, identical tokens)."""
 
+import glob
 import json
 import time
 
@@ -25,6 +28,11 @@ from repro.pool.transfer import TransferEngine
 from repro.sched import Request
 
 CFG = REGISTRY["phi3-mini-3.8b"].reduced()
+#: the scheduler's step span and its phases: the names the serving
+#: benchmark's readers and ``session._overlap_window_s`` match
+PHASES = ("admit_prefill", "collect", "decode", "park_issue")
+RESERVED = ("step",) + PHASES
+SUB_SPANS = ("dispatch", "device_wait")
 
 
 @pytest.fixture(scope="module")
@@ -101,7 +109,7 @@ def test_null_tracer_emits_nothing():
     assert nt.enabled is False
     nt.instant("t", "x")
     nt.complete("t", "x", 0.0, 1.0)
-    with nt.span("t", "x", a=1):
+    with nt.span("t", "x", a=1), nt.step_span("t", "step", 3):
         pass
     assert nt.events() == [] and len(nt) == 0
 
@@ -124,6 +132,10 @@ def test_checker_rejects_corrupt_traces():
     empty = {"traceEvents": [
         {"name": "x", "ph": "i", "ts": 0, "pid": 1, "tid": 0, "s": "t"}]}
     assert any("no complete spans" in e for e in validate_events(empty))
+    anonymous = {"traceEvents": [
+        {"name": "request.queue", "cat": "request", "ph": "X", "ts": 0,
+         "dur": 1.0, "pid": 1, "tid": 0}]}
+    assert any("args.req" in e for e in validate_events(anonymous))
 
 
 def test_checker_wait_ordering():
@@ -328,19 +340,141 @@ def test_session_telemetry_end_to_end(model_and_params, tmp_path):
         assert errs == []
         rep = s.overlap()
         assert rep["transfers"] > 0 and rep["hidden_fraction"] is not None
-        # request lifecycle instants: one full QUEUED→…→DONE per request
-        names = [e.name for e in s.tracer.events() if e.cat == "request"]
-        for name in ("QUEUED", "PREFILL", "DECODE", "DONE"):
+        # request spans: one queue, prefill and decode span per request
+        req_spans = [e for e in s.tracer.events() if e.cat == "request"]
+        names = [e.name for e in req_spans]
+        for name in ("request.queue", "request.prefill", "request.decode"):
             assert names.count(name) == len(out)
         # step phases + pool traffic + per-request histograms all present
         cats = {(e.cat, e.name) for e in s.tracer.events()}
         assert ("sched", "step") in cats and ("pool", "put") in cats
         hists = st["telemetry"]["histograms"]["histograms"]
-        assert hists["req_ttft_steps"]["count"] == len(out)
-        assert hists["req_queue_wait_steps"]["count"] == len(out)
-        assert "req_ttft_steps_bucket" in s.stats_text()
+        for name in ("req_ttft_seconds", "req_queue_wait_seconds",
+                     "req_tpot_seconds"):
+            assert hists[name]["count"] == len(out)
+        # TTFT in seconds is the request's queue and prefill spans
+        assert hists["req_ttft_seconds"]["sum"] == pytest.approx(sum(
+            e.dur for e in req_spans if e.name != "request.decode"))
+        assert "req_ttft_seconds_bucket" in s.stats_text()
     # close() exported to telemetry.trace_path; the file passes the checker
     assert validate_file(path) == []
+
+
+def _request_spans(events):
+    """Each request's ``request.*`` spans in time order."""
+    out = {}
+    for e in events:
+        if e.cat == "request" and e.ph == "X":
+            out.setdefault(e.args["req"], []).append(e)
+    return {r: sorted(v, key=lambda e: e.ts) for r, v in out.items()}
+
+
+def _preempting_requests():
+    """A batch request running alone in one slot, then an interactive
+    arrival whose TTFT deadline preempts it (SLO mode, ``max_batch=1``)."""
+    from repro.slo import SLOSpec
+    vocab = CFG.vocab_size
+    rng = np.random.default_rng(9)
+    return [
+        Request(tokens=rng.integers(0, vocab, 5, dtype=np.int32),
+                max_new_tokens=10, arrival=0.0, seed=0,
+                slo=SLOSpec("batch")),
+        Request(tokens=rng.integers(0, vocab, 4, dtype=np.int32),
+                max_new_tokens=3, arrival=3.0, seed=1,
+                slo=SLOSpec("interactive", ttft_deadline=2.0)),
+    ]
+
+
+def _slo_config():
+    from repro.slo import SLOConfig
+    return OffloadConfig(mode="continuous", max_batch=1, max_seq=32,
+                         slo=SLOConfig(enable=True),
+                         telemetry=TelemetryConfig(enable=True))
+
+
+@pytest.mark.parametrize("preempting", [False, True])
+def test_request_spans_are_contiguous(model_and_params, preempting):
+    """Per request the spans tile submit → retire with no gap: queue,
+    prefill, decode, and around a preemption a queue span of its own;
+    each ``request.prefill`` ends inside a scheduler ``step`` span."""
+    model, params = model_and_params
+    with HyperOffloadSession(_slo_config() if preempting
+                             else _trace()) as s:
+        sched = s.scheduler(model, params)
+        reqs = (_preempting_requests() if preempting else
+                [Request(tokens=np.arange(4 + 2 * i) % CFG.vocab_size,
+                         max_new_tokens=3, seed=i) for i in range(3)])
+        sched.run(reqs)
+        events = s.tracer.events()
+    steps = [e for e in events if e.cat == "sched" and e.name == "step"]
+    spans = _request_spans(events)
+    assert set(spans) == {r.req_id for r in reqs}
+    for rid, seq in spans.items():
+        st = sched.finished[rid]
+        names = [e.name for e in seq]
+        assert names.count("request.queue") == 1 + st.preemptions
+        assert names[0] == "request.queue" and names[-1] == "request.decode"
+        assert seq[0].ts == st.wall_submit
+        assert seq[-1].end == pytest.approx(st.wall_done, abs=1e-9)
+        for a, b in zip(seq, seq[1:]):
+            assert b.ts == pytest.approx(a.end, abs=1e-9)
+        for e in seq:
+            if e.name == "request.prefill":
+                assert any(p.ts <= e.end <= p.end for p in steps)
+    if preempting:
+        assert sum(st.preemptions for st in sched.finished.values()) == 1
+
+
+def test_step_sub_spans_nest_in_phases(model_and_params):
+    """Every ``dispatch`` and ``device_wait`` span lies inside one of the
+    step's phase spans, and the reserved phase names belong to the phase
+    spans alone: at most one of each per step."""
+    with HyperOffloadSession(_trace()) as s:
+        _run_requests(s, model_and_params)
+        events = [e for e in s.tracer.events() if e.ph == "X"]
+    phases = [e for e in events if e.name in PHASES]
+    subs = [e for e in events if e.name in SUB_SPANS]
+    assert {e.name for e in subs} == set(SUB_SPANS)
+    for e in subs:
+        assert e.cat == "sched"
+        assert any(p.tid == e.tid and p.ts <= e.ts and e.end <= p.end
+                   for p in phases), e
+    assert {e.cat for e in events if e.name in RESERVED} == {"sched"}
+    steps = [e for e in events if e.name == "step"]
+    placed = 0
+    for st in steps:
+        inside = [p.name for p in phases
+                  if st.ts <= p.ts and p.end <= st.end]
+        assert all(inside.count(n) <= 1 for n in PHASES), inside
+        placed += len(inside)
+    assert placed == len(phases)
+    assert [p.name for p in phases].count("decode") == len(steps)
+
+
+@pytest.mark.parametrize("enable", [True, False])
+def test_profiler_capture_holds_scheduler_phases(model_and_params, tmp_path,
+                                                 enable):
+    """Under ``jax.profiler.trace`` the host plane carries the scheduler's
+    spans as annotations, on the profiler's clock; telemetry off adds
+    none."""
+    from jax.profiler import ProfileData
+    cfg = OffloadConfig(mode="continuous", max_batch=2, max_seq=32,
+                        chunk_size=6,
+                        telemetry=TelemetryConfig(enable=enable))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with HyperOffloadSession(cfg) as s:
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            _run_requests(s, model_and_params)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    wanted = {"step", "admit_prefill", "decode"} | set(SUB_SPANS)
+    if enable:
+        assert wanted <= names
+    else:
+        assert not wanted & names
 
 
 def test_session_disabled_shape_and_tokens(model_and_params):
@@ -371,25 +505,10 @@ def test_session_slo_counters_in_stats_and_prometheus(model_and_params):
     """SLO counters flow end to end: scheduler → session collector →
     ``stats()['sched']`` → the Prometheus text dump, all agreeing — and
     the preempt/resume lifecycle lands in the trace ring as instants."""
-    from repro.slo import SLOConfig, SLOSpec
-
     model, params = model_and_params
-    vocab = REGISTRY["phi3-mini-3.8b"].reduced().vocab_size
-    rng = np.random.default_rng(9)
-    reqs = [
-        Request(tokens=rng.integers(0, vocab, 5, dtype=np.int32),
-                max_new_tokens=10, arrival=0.0, seed=0,
-                slo=SLOSpec("batch")),
-        Request(tokens=rng.integers(0, vocab, 4, dtype=np.int32),
-                max_new_tokens=3, arrival=3.0, seed=1,
-                slo=SLOSpec("interactive", ttft_deadline=2.0)),
-    ]
-    cfg = OffloadConfig(mode="continuous", max_batch=1, max_seq=32,
-                        slo=SLOConfig(enable=True),
-                        telemetry=TelemetryConfig(enable=True))
-    with HyperOffloadSession(cfg) as s:
+    with HyperOffloadSession(_slo_config()) as s:
         sched = s.scheduler(model, params)
-        sched.run(reqs)
+        sched.run(_preempting_requests())
         st = s.stats()["sched"]
         assert st["preemptions"] == 1 and st["resumes"] == 1
         assert st["shed"] == 0
