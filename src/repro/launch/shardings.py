@@ -69,8 +69,8 @@ MOE_LOGICAL: Dict[str, Tuple[Optional[str], ...]] = {
 }
 
 CACHE_LOGICAL: Dict[str, Tuple[Optional[str], ...]] = {
-    "k": ("cache_batch", "cache_seq", "cache_heads", None),
-    "v": ("cache_batch", "cache_seq", "cache_heads", None),
+    "k": ("cache_batch", "cache_seq", "cache_heads"),   # heads flat: Hkv·D
+    "v": ("cache_batch", "cache_seq", "cache_heads"),
     "xk": ("cache_batch", "frames", "cache_heads", None),
     "xv": ("cache_batch", "frames", "cache_heads", None),
     "ckv": ("cache_batch", "cache_seq", None),

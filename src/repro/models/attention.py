@@ -4,6 +4,14 @@ MLA (multi-head latent attention), cross-attention, and their decode paths.
 KV caches for sliding-window layers are ring buffers of capacity
 ``min(window, max_seq)`` — token ``t`` lives in slot ``t % C`` — so a
 windowed layer at 500k context holds only ``window`` tokens of KV.
+
+A self-attention layer caches K and V as ``(B, C, Hkv·D)``: heads side by
+side on one minor axis, which on a TPU is lane-aligned (a 96-wide head
+minor would pad to 128, so the device would store a ``(…, Hkv, D)`` cache
+transposed and every decode step would relayout it). Decode reads and
+writes the cache in that layout and scores heads with a 0/1 head matrix
+instead of reshaping it; chunked prefill reshapes its one cached row into
+heads.
 """
 
 from __future__ import annotations
@@ -15,7 +23,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.models import runtime
-from repro.models.common import apply_rope, dense_init, rmsnorm, softcap
+from repro.models.common import (
+    apply_rope, apply_rope_flat, dense_init, rmsnorm, softcap,
+)
 from repro.sharding.rules import constrain
 
 NEG_INF = -2.3819763e38  # same constant XLA uses for -inf masking in f32
@@ -89,9 +99,10 @@ def init_attn_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int,
             "krope": jnp.zeros((batch, c, m.qk_rope_head_dim), dtype),
         }
     else:
+        kv = cfg.n_kv_heads * cfg.head_dim
         cache = {
-            "k": jnp.zeros((batch, c, cfg.n_kv_heads, cfg.head_dim), dtype),
-            "v": jnp.zeros((batch, c, cfg.n_kv_heads, cfg.head_dim), dtype),
+            "k": jnp.zeros((batch, c, kv), dtype),
+            "v": jnp.zeros((batch, c, kv), dtype),
         }
     if spec.cross_attn:
         assert enc_frames is not None
@@ -335,8 +346,10 @@ def attention_prefill(cfg, spec, p, x, positions, cache, *,
         if cfg.rope_mode in ("rope", "mrope"):
             sections = cfg.mrope_sections if cfg.rope_mode == "mrope" else None
             k = apply_rope(k, positions, cfg.rope_theta, sections)
-        new_cache["k"] = _ring_write_seq(cache["k"], k.astype(cache["k"].dtype))
-        new_cache["v"] = _ring_write_seq(cache["v"], v.astype(cache["v"].dtype))
+        new_cache["k"] = _ring_write_seq(
+            cache["k"], k.reshape(b, s, hkv * hd).astype(cache["k"].dtype))
+        new_cache["v"] = _ring_write_seq(
+            cache["v"], v.reshape(b, s, hkv * hd).astype(cache["v"].dtype))
     if spec.cross_attn and enc_out is not None:
         xk, xv = cross_attention_kv(cfg, p, enc_out)
         new_cache["xk"] = xk.astype(cache["xk"].dtype)
@@ -421,6 +434,8 @@ def attention_prefill_chunk(
         sections = cfg.mrope_sections if cfg.rope_mode == "mrope" else None
         q = apply_rope(q, positions, cfg.rope_theta, sections)
         k = apply_rope(k, positions, cfg.rope_theta, sections)
+    k_hist = cache["k"].reshape(b, c, hkv, hd)
+    v_hist = cache["v"].reshape(b, c, hkv, hd)
     window = spec.window
     if swa_override is not None and window is None:
         window = swa_override
@@ -439,19 +454,21 @@ def attention_prefill_chunk(
     if window is not None:
         m_hist = m_hist & (hj[None, :] > qi[:, None] - window)
         m_chunk = m_chunk & (ii[None, :] > ii[:, None] - window)
-    sc_hist = _gqa_scores(q, cache["k"]) * scale         # (B,S,Hq,C)
+    sc_hist = _gqa_scores(q, k_hist) * scale             # (B,S,Hq,C)
     sc_chunk = _gqa_scores(q, k) * scale                 # (B,S,Hq,S)
     scores = jnp.concatenate([sc_hist, sc_chunk], axis=-1)
     scores = softcap(scores, cfg.attn_logit_softcap)
     mask = jnp.concatenate([m_hist, m_chunk], axis=-1)   # (S, C+S)
     probs = _masked_softmax(scores, mask[None, :, None, :])
-    v_all = jnp.concatenate([cache["v"], v.astype(cache["v"].dtype)], axis=1)
+    v_all = jnp.concatenate([v_hist, v.astype(v_hist.dtype)], axis=1)
     out = _gqa_out(probs, v_all).astype(x.dtype).reshape(b, s, hq * hd)
     out = out @ p["wo"]
 
     new_cache = dict(cache)
-    new_cache["k"] = _ring_write_at(cache["k"], k, offset, valid_len)
-    new_cache["v"] = _ring_write_at(cache["v"], v, offset, valid_len)
+    new_cache["k"] = _ring_write_at(cache["k"], k.reshape(b, s, hkv * hd),
+                                    offset, valid_len)
+    new_cache["v"] = _ring_write_at(cache["v"], v.reshape(b, s, hkv * hd),
+                                    offset, valid_len)
     return out, new_cache
 
 
@@ -513,33 +530,82 @@ def attention_decode(
     positions: jax.Array,   # (B, 1) or (3, B, 1) rope positions of this token
     cache: Dict,
     *,
+    layer: jax.Array,
     swa_override: Optional[int] = None,
 ) -> Tuple[jax.Array, Dict]:
+    """One token against the cache. A self-attention layer's
+    ``cache["k"]``/``["v"]`` are the whole layer stack ``(L, B, C,
+    Hkv·D)``: the token is written into layer ``layer`` in place and that
+    layer is read where it lies, so the decode scan never copies a layer
+    out of the stack and back. MLA caches are one layer's."""
     if spec.mixer == "mla":
         return _mla_decode(cfg, p, x, pos, positions, cache)
-    b, _, d = x.shape
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    c = cache["k"].shape[1]
-    q = (x @ p["wq"]).reshape(b, 1, hq, hd)
-    k = (x @ p["wk"]).reshape(b, 1, hkv, hd)
-    v = (x @ p["wv"]).reshape(b, 1, hkv, hd)
+    hd = cfg.head_dim
+    q = x @ p["wq"]                               # (B, 1, Hq·D), heads flat
+    k = x @ p["wk"]
+    v = x @ p["wv"]
     if cfg.rope_mode in ("rope", "mrope"):
         sections = cfg.mrope_sections if cfg.rope_mode == "mrope" else None
-        q = apply_rope(q, positions, cfg.rope_theta, sections)
-        k = apply_rope(k, positions, cfg.rope_theta, sections)
-    new_k = _ring_write_token(cache["k"], k, pos)
-    new_v = _ring_write_token(cache["v"], v, pos)
+        q = apply_rope_flat(q, positions, cfg.rope_theta, hd, sections)
+        k = apply_rope_flat(k, positions, cfg.rope_theta, hd, sections)
+    new_k = _ring_write_token(cache["k"], k, pos, layer)
+    new_v = _ring_write_token(cache["v"], v, pos, layer)
+    k_l = jax.lax.dynamic_index_in_dim(new_k, layer, 0, keepdims=False)
+    v_l = jax.lax.dynamic_index_in_dim(new_v, layer, 0, keepdims=False)
     scale = cfg.query_scale if cfg.query_scale is not None else hd ** -0.5
-    scores = _gqa_scores(q, new_k) * scale       # (B,1,Hq,C)
+    scores = _flat_scores(q[:, 0], k_l, hd) * scale       # (B,Hq,C)
     scores = softcap(scores, cfg.attn_logit_softcap)
-    valid = _ring_valid_mask(pos, c)             # (C,) or (B,C)
-    scores = _apply_valid_mask(scores, valid)
+    scores = _apply_valid_mask(scores, _ring_valid_mask(pos, k_l.shape[1]))
     probs = jax.nn.softmax(scores, axis=-1)
-    out = _gqa_out(probs, new_v).astype(x.dtype).reshape(b, 1, hq * hd)
+    out = _flat_out(probs, v_l, hd).astype(x.dtype)[:, None]   # (B,1,Hq·D)
     out = out @ p["wo"]
     new_cache = dict(cache)
     new_cache["k"], new_cache["v"] = new_k, new_v
     return out, new_cache
+
+
+def _head_matrix(hkv: int, hd: int, dtype) -> jax.Array:
+    """(Hkv·D, Hkv) 0/1: lane x of a flat K/V row belongs to head x // D."""
+    return (jnp.arange(hkv * hd)[:, None] // hd
+            == jnp.arange(hkv)[None, :]).astype(dtype)
+
+
+def _flat_scores(q: jax.Array, k: jax.Array, hd: int) -> jax.Array:
+    """q: (B, Hq·D), k: (B, C, Hkv·D) -> scores (B, Hq, C) in f32.
+
+    The query is spread into a block-diagonal (B, Hkv·D, Hq): query head
+    h = kv·g + j keeps its D lanes in kv head kv's rows and zeros
+    elsewhere, so one matmul per row contracts over the cache's flat minor
+    axis and yields every head's scores. Operands stay in the cache dtype;
+    the sums are f32."""
+    b, qd = q.shape
+    x = k.shape[-1]
+    hkv, hq = x // hd, qd // hd
+    g = hq // hkv
+    eye = _head_matrix(hkv, hd, k.dtype)
+    # (B, Hq·D) -> (B, g, Hkv·D): group member j of every kv head, in the
+    # kv heads' lane order (a no-op for g == 1)
+    qg = q.astype(k.dtype).reshape(b, hkv, g, hd).transpose(0, 2, 1, 3)
+    qg = qg.reshape(b, g, x)
+    qbd = qg.transpose(0, 2, 1)[:, :, None, :] * eye[None, :, :, None]
+    qbd = qbd.reshape(b, x, hq)                       # head index kv·g + j
+    return jnp.einsum("bcx,bxh->bhc", k, qbd,
+                      preferred_element_type=jnp.float32)
+
+
+def _flat_out(probs: jax.Array, v: jax.Array, hd: int) -> jax.Array:
+    """probs: (B, Hq, C), v: (B, C, Hkv·D) -> (B, Hq·D) in f32: every query
+    head weighs all lanes, then keeps its own kv head's D of them."""
+    b, hq, _ = probs.shape
+    x = v.shape[-1]
+    hkv = x // hd
+    g = hq // hkv
+    full = jnp.einsum("bhc,bcx->bhx", probs.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)  # (B, Hq, Hkv·D)
+    eye = _head_matrix(hkv, hd, jnp.float32)
+    own = jnp.einsum("bkjx,xk->bjx", full.reshape(b, hkv, g, x), eye)
+    # (B, g, Hkv·D) -> (B, Hq·D), head kv·g + j (a no-op for g == 1)
+    return own.reshape(b, g, hkv, hd).transpose(0, 2, 1, 3).reshape(b, hq * hd)
 
 
 def cross_attention_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict) -> jax.Array:
@@ -566,28 +632,30 @@ def _ring_valid_mask(pos: jax.Array, c: int) -> jax.Array:
 
 
 def _apply_valid_mask(scores: jax.Array, valid: jax.Array) -> jax.Array:
-    """Mask decode scores (B,1,H,C) with a (C,) or per-row (B,C) mask."""
-    if valid.ndim == 1:
-        valid = valid[None, None, None, :]
-    else:
-        valid = valid[:, None, None, :]
+    """Mask decode scores (B,...,C) with a (C,) or per-row (B,C) mask."""
+    if valid.ndim == 2:
+        valid = valid.reshape(valid.shape[:1] + (1,) * (scores.ndim - 2)
+                              + valid.shape[1:])
     return jnp.where(valid, scores, NEG_INF)
 
 
-def _ring_write_token(buf: jax.Array, vals: jax.Array, pos: jax.Array) -> jax.Array:
-    """Write one token's entries (B,1,...) into the ring buffer (B,C,...).
+def _ring_write_token(buf: jax.Array, vals: jax.Array, pos: jax.Array,
+                      layer: Optional[jax.Array] = None) -> jax.Array:
+    """Write one token's entries (B,1,...) into the ring buffer (B,C,...),
+    or, given ``layer``, in place into that layer of a stack (L,B,C,...).
 
     Scalar ``pos`` writes every row at the same slot (uniform batch); a
     (B,) ``pos`` writes row i at its own slot ``pos[i] % C`` — the
     continuous-batching case where requests sit at different positions.
     """
-    c = buf.shape[1]
+    if layer is None:
+        return _ring_write_token(buf[None], vals, pos, 0)[0]
+    b, c = buf.shape[1], buf.shape[2]
     vals = vals.astype(buf.dtype)
     if jnp.ndim(pos) == 0:
-        return jax.lax.dynamic_update_slice_in_dim(buf, vals, jnp.mod(pos, c),
-                                                   axis=1)
-    b = buf.shape[0]
-    return buf.at[jnp.arange(b), jnp.mod(pos, c)].set(vals[:, 0])
+        start = (layer, 0, jnp.mod(pos, c)) + (0,) * (buf.ndim - 3)
+        return jax.lax.dynamic_update_slice(buf, vals[None], start)
+    return buf.at[layer, jnp.arange(b), jnp.mod(pos, c)].set(vals[:, 0])
 
 
 def _mla_decode(cfg, p, x, pos, positions, cache):

@@ -214,18 +214,21 @@ def apply_layer_decode(
     spec: LayerSpec,
     p: Dict,
     x: jax.Array,            # (B, 1, D)
-    pos: jax.Array,          # scalar
+    pos: jax.Array,          # scalar, or (B,) per-row
     positions: jax.Array,    # (B,1) or (3,B,1)
     cache: Dict,
     *,
+    layer: jax.Array,
     swa_override: Optional[int] = None,
 ) -> Tuple[jax.Array, Dict]:
+    """``layer``: the index into the K/V stacks of a self-attention layer
+    (see ``attention.attention_decode``)."""
     h = apply_norm(cfg, p["pre_norm"], x)
     if spec.mixer == "mamba2":
         h, new_cache = ssm_mod.mamba_decode(cfg, p["mixer"], h, cache)
     else:
         h, new_cache = attn.attention_decode(
-            cfg, spec, p["mixer"], h, pos, positions, cache,
+            cfg, spec, p["mixer"], h, pos, positions, cache, layer=layer,
             swa_override=swa_override)
     if spec.post_norms:
         h = apply_norm(cfg, p["post_norm"], h)

@@ -75,6 +75,30 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
+def _rope_angles(
+    positions: jax.Array,
+    inv: jax.Array,
+    mrope_sections: Optional[Tuple[int, int, int]],
+) -> jax.Array:
+    """Rotation angles (B, S, half) of ``positions`` (B, S), or of M-RoPE's
+    three planes (3, B, S) [arXiv:2409.12191], where ``mrope_sections``
+    partitions the half frequency channels among the planes."""
+    half = inv.shape[0]
+    if mrope_sections is not None:
+        assert positions.ndim == 3 and positions.shape[0] == 3, positions.shape
+        assert sum(mrope_sections) == half, (mrope_sections, half)
+        # per-channel section id -> select the matching position plane
+        sec_id = jnp.repeat(
+            jnp.arange(3), jnp.array(mrope_sections), total_repeat_length=half
+        )  # (half,)
+        sec_onehot = jax.nn.one_hot(sec_id, 3, dtype=jnp.float32)  # (half, 3)
+        pos = positions.astype(jnp.float32)  # (3, B, S)
+        ang_all = pos[..., None] * inv[None, None, None, :]  # (3, B, S, half)
+        return jnp.einsum("pbsh,hp->bsh", ang_all, sec_onehot)  # (B, S, half)
+    pos = positions.astype(jnp.float32)  # (B, S)
+    return pos[..., None] * inv[None, None, :]  # (B, S, half)
+
+
 def apply_rope(
     x: jax.Array,
     positions: jax.Array,
@@ -89,27 +113,38 @@ def apply_rope(
     """
     d = x.shape[-1]
     half = d // 2
-    inv = rope_freqs(d, theta)  # (half,)
-    if mrope_sections is not None:
-        assert positions.ndim == 3 and positions.shape[0] == 3, positions.shape
-        assert sum(mrope_sections) == half, (mrope_sections, half)
-        # per-channel section id -> select the matching position plane
-        sec_id = jnp.repeat(
-            jnp.arange(3), jnp.array(mrope_sections), total_repeat_length=half
-        )  # (half,)
-        sec_onehot = jax.nn.one_hot(sec_id, 3, dtype=jnp.float32)  # (half, 3)
-        pos = positions.astype(jnp.float32)  # (3, B, S)
-        ang_all = pos[..., None] * inv[None, None, None, :]  # (3, B, S, half)
-        ang = jnp.einsum("pbsh,hp->bsh", ang_all, sec_onehot)  # (B, S, half)
-    else:
-        pos = positions.astype(jnp.float32)  # (B, S)
-        ang = pos[..., None] * inv[None, None, :]  # (B, S, half)
+    ang = _rope_angles(positions, rope_freqs(d, theta), mrope_sections)
     sin = jnp.sin(ang)[..., None, :]  # (B, S, 1, half)
     cos = jnp.cos(ang)[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
+    return out.astype(x.dtype)
+
+
+def apply_rope_flat(
+    x: jax.Array,
+    positions: jax.Array,
+    theta: float,
+    head_dim: int,
+    mrope_sections: Optional[Tuple[int, int, int]] = None,
+) -> jax.Array:
+    """``apply_rope`` on heads laid side by side: ``x`` is (B, S, H·D) and
+    each ``head_dim``-wide block is rotated as ``apply_rope`` rotates a
+    head. ``x`` is never reshaped into heads, so the projection that made
+    it is read in its stored layout, with no transposed copy of its
+    weight."""
+    half = head_dim // 2
+    heads = x.shape[-1] // head_dim
+    ang = _rope_angles(positions, rope_freqs(head_dim, theta), mrope_sections)
+    cos = jnp.tile(jnp.cos(ang), 2 * heads)             # (B, S, H·D)
+    sin = jnp.tile(jnp.sin(ang), 2 * heads)
+    low = (jnp.arange(x.shape[-1]) % head_dim) < half   # x1 of each head
+    # rotate-half inside each head: x1 -> -x2, x2 -> x1
+    partner = jnp.where(low, jnp.roll(x, -half, axis=-1),
+                        jnp.roll(x, half, axis=-1))
+    out = x * cos + partner * jnp.where(low, -sin, sin)
     return out.astype(x.dtype)
 
 

@@ -86,9 +86,9 @@ class Model:
             for seg in self.cfg.segments for spec in seg.pattern)
 
     def decode_step(self, params: Dict, cache: Dict, token: jax.Array,
-                    pos: jax.Array, inplace: bool = True) -> Tuple[jax.Array, Dict]:
+                    pos: jax.Array) -> Tuple[jax.Array, Dict]:
         return tfm.decode_step(self.cfg, params, cache, token, pos,
-                               swa_override=self.swa_override, inplace=inplace)
+                               swa_override=self.swa_override)
 
     # -- dry-run specs --------------------------------------------------------
     def param_specs(self, dtype=jnp.bfloat16) -> Any:

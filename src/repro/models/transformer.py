@@ -5,7 +5,7 @@ Parameters for each repeated layer pattern are stacked along a leading
 one pattern body is traced/compiled regardless of depth, which keeps the
 HLO small enough to compile 80-layer production configs with 512 host
 devices on the dry-run machine. KV/SSM caches share the same stacked
-layout so decode scans carry them as scan xs/ys.
+layout: prefill scans them as xs/ys, decode carries them.
 """
 
 from __future__ import annotations
@@ -323,7 +323,6 @@ def decode_step(
     pos: jax.Array,     # scalar int32 — or (B,) per-row indices being written
     *,
     swa_override: Optional[int] = None,
-    inplace: bool = True,
 ) -> Tuple[jax.Array, Dict]:
     """One autoregressive step. Returns (logits (B,1,V), new cache).
 
@@ -332,12 +331,13 @@ def decode_step(
     sequence position; rows are independent, so per-row results equal the
     corresponding single-request decode).
 
-    ``inplace=True`` (default) threads the stacked cache through the layer
-    scan as a CARRY updated with dynamic slice writes — the while-loop state
-    aliases across iterations, so decode scratch is ~a single layer's
-    working set. ``inplace=False`` is the naive xs→ys scan, which
-    double-buffers the whole cache (≈2.6× cache in scratch) and exists as
-    the recorded §Perf hillclimb-C baseline."""
+    The stacked cache is the layer scan's CARRY, so the while-loop state
+    aliases across iterations and is updated in place. A self-attention
+    layer's K/V stacks go to the layer whole (``apply_layer_decode(...,
+    layer=r)``): it writes its token into layer r and reads layer r where
+    it lies, so no iteration copies a layer's cache out and back. Every
+    other leaf (MLA latents, SSM state, cross-attention K/V) is sliced out
+    for its layer and written back."""
     b = token.shape[0]
     if jnp.ndim(pos) == 0:
         positions = jnp.broadcast_to(pos[None, None], (b, 1)).astype(jnp.int32)
@@ -351,45 +351,30 @@ def decode_step(
     for seg, seg_params, seg_cache in zip(
             cfg.segments, params["segments"], cache["segments"]):
 
-        if inplace:
-            # cache as scan CARRY with dynamic in-place slice updates: the
-            # while-loop state aliases across iterations, so the stacked KV
-            # buffer is updated in place instead of double-buffered as ys
-            def carry_body(carry, xs, seg=seg):
-                h, cache_st = carry
-                layer_params, r = xs
-                layer_cache = jax.tree.map(
-                    lambda v: jax.lax.dynamic_index_in_dim(v, r, 0, keepdims=False),
-                    cache_st)
-                for i, spec in enumerate(seg.pattern):
-                    h, c = blocks.apply_layer_decode(
-                        cfg, spec, layer_params[f"p{i}"], h, pos, positions,
-                        layer_cache[f"p{i}"], swa_override=swa_override)
-                    layer_cache[f"p{i}"] = c
-                cache_st = jax.tree.map(
-                    lambda buf, v: jax.lax.dynamic_update_index_in_dim(
-                        buf, v.astype(buf.dtype), r, 0),
-                    cache_st, layer_cache)
-                return (h, cache_st), None
-
-            (x, seg_cache), _ = jax.lax.scan(
-                carry_body, (x, seg_cache),
-                (seg_params, jnp.arange(seg.repeats)))
-            new_cache["segments"].append(seg_cache)
-            continue
-
-        def scan_body(h, xs, seg=seg):
-            layer_params, layer_cache = xs
-            out_cache = {}
+        def carry_body(carry, xs, seg=seg):
+            h, cache_st = carry
+            layer_params, r = xs
+            cache_st = dict(cache_st)
             for i, spec in enumerate(seg.pattern):
+                st = cache_st[f"p{i}"]
+                whole = ("k", "v") if spec.mixer == "attn" else ()
+                layer_cache = {
+                    n: v if n in whole
+                    else jax.lax.dynamic_index_in_dim(v, r, 0, keepdims=False)
+                    for n, v in st.items()}
                 h, c = blocks.apply_layer_decode(
                     cfg, spec, layer_params[f"p{i}"], h, pos, positions,
-                    layer_cache[f"p{i}"], swa_override=swa_override)
-                out_cache[f"p{i}"] = c
-            return h, out_cache
+                    layer_cache, layer=r, swa_override=swa_override)
+                cache_st[f"p{i}"] = {
+                    n: c[n] if n in whole
+                    else jax.lax.dynamic_update_index_in_dim(
+                        v, c[n].astype(v.dtype), r, 0)
+                    for n, v in st.items()}
+            return (h, cache_st), None
 
-        x, seg_new_cache = jax.lax.scan(scan_body, x, (seg_params, seg_cache))
-        new_cache["segments"].append(seg_new_cache)
+        (x, seg_cache), _ = jax.lax.scan(
+            carry_body, (x, seg_cache), (seg_params, jnp.arange(seg.repeats)))
+        new_cache["segments"].append(seg_cache)
 
     logits = final_logits(cfg, params, x)
     return logits, new_cache

@@ -261,10 +261,12 @@ class PagedKVCache:
             self._flush_tail()
 
     def prefill(self, k_seq: jax.Array, v_seq: jax.Array) -> None:
-        """Bulk-append a prompt: (B, S, Hkv, D), the model's layout."""
-        s = k_seq.shape[1]
-        k_seq = k_seq.transpose(0, 2, 1, 3).astype(self.dtype)
-        v_seq = v_seq.transpose(0, 2, 1, 3).astype(self.dtype)
+        """Bulk-append a prompt: (B, S, Hkv·D), the model's cache layout,
+        or (B, S, Hkv, D)."""
+        b, s = k_seq.shape[:2]
+        heads = (b, s, self.n_kv_heads, self.head_dim)
+        k_seq = k_seq.reshape(heads).transpose(0, 2, 1, 3).astype(self.dtype)
+        v_seq = v_seq.reshape(heads).transpose(0, 2, 1, 3).astype(self.dtype)
         n_full = s // self.page_size
         for pi in range(n_full):
             sl = slice(pi * self.page_size, (pi + 1) * self.page_size)

@@ -1,12 +1,16 @@
 """Per-architecture smoke tests: REDUCED variant of each assigned arch —
 one forward/train step on CPU, asserting output shapes and no NaNs, plus
-prefill+decode consistency with the full forward."""
+prefill+decode consistency with the full forward, and per-row decode
+against a whole-sequence prefill."""
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import ARCH_IDS, REGISTRY
+from repro.configs.base import Segment
 from repro.data.pipeline import SyntheticTokens
 from repro.models.model import build_model
 from repro.training.step import TrainStepConfig, init_train_state, make_train_step
@@ -81,6 +85,63 @@ def test_prefill_decode_matches_forward(arch):
     lg_dec, cache = m.decode_step(params, cache,
                                   batch["tokens"][:, s - 1:s], jnp.int32(s - 1))
     assert jnp.max(jnp.abs(lg_dec[:, 0] - logits_full[:, s - 1])) < 1e-3
+
+
+def _stacked_smoke(arch, n_kv_heads=None, window=None):
+    """The arch's smoke config with every segment repeated twice, so the
+    decode scan writes and reads a layer stack deeper than one layer;
+    optionally fewer KV heads (GQA) or a short sliding window."""
+    cfg = REGISTRY[arch].reduced()
+    segments = tuple(
+        Segment(pattern=tuple(
+            dataclasses.replace(spec, window=window)
+            if window is not None and spec.window is not None else spec
+            for spec in seg.pattern), repeats=2)
+        for seg in cfg.segments)
+    return dataclasses.replace(
+        cfg, segments=segments, n_kv_heads=n_kv_heads or cfg.n_kv_heads)
+
+
+PER_ROW_CASES = {
+    "mha": lambda: _stacked_smoke("phi3-mini-3.8b"),
+    "gqa": lambda: _stacked_smoke("granite-moe-3b-a800m", n_kv_heads=2),
+    # local layers keep a 5-slot ring, which every row wraps; logit softcap
+    "window-softcap": lambda: _stacked_smoke("gemma2-9b", window=5),
+    "ssm-hybrid": lambda: _stacked_smoke("zamba2-7b"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PER_ROW_CASES))
+def test_per_row_decode_matches_prefill(case):
+    """Two rows at different positions share one batched decode (per-row
+    ``pos``, as the continuous scheduler calls it); each step's logits
+    equal a whole-sequence prefill of that row up to the same position."""
+    cfg = PER_ROW_CASES[case]()
+    if case == "window-softcap":
+        assert cfg.attn_logit_softcap is not None
+    m = build_model(cfg)
+    params = m.init(jax.random.key(0))
+    max_seq, lens, steps = 16, (3, 9), 6
+    toks = jax.random.randint(jax.random.key(3), (2, max(lens) + steps),
+                              0, cfg.vocab_size)
+
+    def prefill_row(r, n):
+        return m.prefill(params, {"tokens": toks[r:r + 1, :n]},
+                         m.init_cache(1, max_seq))
+
+    cache = m.init_cache(2, max_seq)
+    for r, n in enumerate(lens):
+        _, row = prefill_row(r, n)
+        cache = jax.tree.map(lambda big, one, r=r: big.at[:, r].set(one[:, 0]),
+                             cache, row)
+    pos = jnp.array(lens, jnp.int32)
+    for _ in range(steps):
+        tok = toks[jnp.arange(2), pos][:, None]
+        lg, cache = m.decode_step(params, cache, tok, pos)
+        for r in range(2):
+            want, _ = prefill_row(r, int(pos[r]) + 1)
+            assert jnp.max(jnp.abs(lg[r, 0] - want[0, 0])) < 1e-3, (r, pos)
+        pos = pos + 1
 
 
 def test_training_learns_synthetic_structure():

@@ -66,8 +66,9 @@ def test_sliding_window_decode_equals_full_with_window_mask():
     cache = A.init_attn_cache(cfg, spec_w, b, s, jnp.float32)
     assert cache["k"].shape[1] == 6  # ring capacity = window
     _, cache = A.attention_prefill(cfg, spec_w, p, x[:, : s - 1], pos[:, : s - 1], cache)
+    stack = jax.tree.map(lambda v: v[None], cache)   # a one-layer stack
     out, _ = A.attention_decode(cfg, spec_w, p, x[:, s - 1 :], jnp.int32(s - 1),
-                                pos[:, s - 1 :], cache)
+                                pos[:, s - 1 :], stack, layer=0)
     np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(full[:, -1]),
                                atol=2e-5)
 

@@ -110,6 +110,29 @@ def test_paged_kvcache_all_pages_exact():
     assert cache.flushes == 3
 
 
+def test_paged_kvcache_prefill_takes_model_cache_layout():
+    """A prompt in the model's cache layout (B, S, Hkv·D) lands in the
+    same pages and tail as the same prompt split into heads."""
+    b, hkv, d, page, s0 = 2, 2, 16, 8, 21
+    ks = jax.random.split(jax.random.key(1), 2)
+    k_seq = jax.random.normal(ks[0], (b, s0, hkv, d))
+    v_seq = jax.random.normal(ks[1], (b, s0, hkv, d))
+    caches = []
+    for k, v in ((k_seq, v_seq), (k_seq.reshape(b, s0, hkv * d),
+                                  v_seq.reshape(b, s0, hkv * d))):
+        c = PagedKVCache.create(batch=b, max_seq=64, page_size=page,
+                                n_kv_heads=hkv, head_dim=d,
+                                pool=default_pool())
+        c.prefill(k, v)
+        caches.append(c)
+    heads, flat = caches
+    assert flat.full_pages == heads.full_pages == 2
+    for a, z in ((heads.fetch_pages(range(2)), flat.fetch_pages(range(2))),
+                 ((heads.k_tail, heads.v_tail), (flat.k_tail, flat.v_tail))):
+        for x, y in zip(a, z):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 def test_paged_kvcache_append_flush_and_sparse_selection():
     b, hq, hkv, d, page = 1, 2, 1, 16, 4
     cache = PagedKVCache.create(batch=b, max_seq=32, page_size=page,

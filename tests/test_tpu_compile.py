@@ -4,14 +4,18 @@ The TPU compiler is installed even where no chip is. These tests compile
 the serving path's attention kernels at phi3-mini-3.8b widths and its
 full-width bfloat16 decode step for a described v5e, and read what the
 compiler reports: a Mosaic kernel in each kernel's program (so it is not
-interpreted) and a decode step that fits the chip's 16 GB of HBM.
+interpreted), a decode step that fits the chip's 16 GB of HBM, and a
+decode step whose layer loop reads the cache and the weights where they
+lie, with no per-layer copy of either.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +32,7 @@ from repro.models.model import build_model
 
 V5E_HBM_BYTES = 16 * 10**9
 H, D, PAGE, BATCH, MAX_SEQ = 32, 96, 32, 4, 1024   # phi3-mini-3.8b serving
+CHAT_BATCH, CHAT_SEQ = 8, 768        # the resident chat benchmark's shape
 
 
 @pytest.fixture(scope="module")
@@ -103,3 +108,76 @@ def test_full_width_decode_step_fits_one_v5e(one_chip):
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes > 7 * 10**9   # 3.8B bf16 params
     assert used < V5E_HBM_BYTES, used
+
+
+# --- reading the optimized HLO text --------------------------------------
+
+_DTYPE_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4,
+                "s8": 1, "u8": 1, "pred": 1}
+_INSTR = re.compile(r"(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\((.*)")
+
+
+def _computations(hlo):
+    """{computation name: [instruction lines]}; the entry also as ""."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(2), [])
+            if head.group(1):
+                comps[""] = cur
+        elif line == "}":
+            cur = None
+        elif cur is not None and line.strip():
+            cur.append(line.strip())
+    return comps
+
+
+def _ops(comps, name):
+    """(opcode, instruction name, logical output bytes) per instruction
+    of a computation; a fusion's opcode is its fused root's, so a fused
+    slice or relayout reads as ``dynamic-slice`` or ``copy``."""
+    for line in comps[name]:
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        iname, shape, op, rest = m.groups()
+        called = re.search(r"calls=%([\w.\-]+)", rest)
+        if op == "fusion" and called:
+            root = [x for x in comps[called.group(1)] if x.startswith("ROOT")]
+            op = _INSTR.match(root[0]).group(3)
+        dims = re.match(r"(\w+)\[([\d,]*)\]", shape)
+        nbytes = 0
+        if dims and dims.group(1) in _DTYPE_BYTES:
+            nbytes = _DTYPE_BYTES[dims.group(1)] * math.prod(
+                int(d) for d in dims.group(2).split(",") if d)
+        yield op, iname, nbytes
+
+
+def test_decode_layer_loop_reads_cache_and_weights_in_place(one_chip):
+    """phi3-mini-3.8b's bfloat16 decode step at the chat benchmark's shape
+    (batch 8, 768-slot cache, per-row positions, cache donated). In the
+    layer scan's loop body nothing as large as a projection weight (3072²
+    bf16, 18.9 MB) or a layer's K or V (37.7 MB) is sliced out or relaid
+    out, and the entry copies no cache-sized buffer: each step reads the
+    cache and the weights once, in their stored layout."""
+    model = build_model(REGISTRY["phi3-mini-3.8b"])
+    on_chip = lambda s: _spec(s.shape, one_chip, s.dtype)
+    params = jax.tree.map(on_chip, model.param_specs(jnp.bfloat16))
+    cache = jax.tree.map(on_chip, model.cache_specs(
+        CHAT_BATCH, CHAT_SEQ, jnp.bfloat16))
+    token = _spec((CHAT_BATCH, 1), one_chip, jnp.int32)
+    pos = _spec((CHAT_BATCH,), one_chip, jnp.int32)
+    hlo = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, token, pos).compile().as_text()
+    comps = _computations(hlo)
+    bodies = re.findall(r"body=%([\w.\-]+)", "\n".join(comps[""]))
+    assert len(bodies) == 1, bodies          # one segment, one layer scan
+    weight = 3072 * 3072 * 2
+    moved = [(op, name, n) for op, name, n in _ops(comps, bodies[0])
+             if op in ("copy", "transpose", "dynamic-slice") and n >= weight]
+    assert not moved, moved
+    leaf = CHAT_BATCH * CHAT_SEQ * 3072 * 2 * 32
+    entry_copies = [(op, name, n) for op, name, n in _ops(comps, "")
+                    if op in ("copy", "transpose") and n >= leaf]
+    assert not entry_copies, entry_copies
