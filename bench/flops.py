@@ -6,7 +6,8 @@ A token's forward pass at context length ``ctx`` (it attends ``ctx``
 keys, itself included) costs two operations per weight it multiplies by,
 plus ``4 * layers * heads * head_dim * ctx`` for the scores and the
 weighted sum of values. A mixture-of-experts layer multiplies by the
-router and by ``num_experts_per_tok`` experts. The output head is counted
+router and by ``num_experts_per_tok`` experts: a layer has experts where
+the file has ``num_local_experts``. The output head is counted
 once per emitted token, since only those logits are needed.
 """
 
@@ -23,7 +24,7 @@ def _attn_weights(cfg: Dict) -> int:
 
 def _ffn_weights(cfg: Dict, active: bool) -> int:
     d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    if cfg["reference"] == "moe":
+    if "num_local_experts" in cfg:
         e = cfg["num_experts_per_tok"] if active else cfg["num_local_experts"]
         return d * cfg["num_local_experts"] + e * 3 * d * f
     return 3 * d * f
@@ -50,7 +51,7 @@ def decode_weight_bytes(cfg: Dict) -> int:
     layers = cfg["num_hidden_layers"]
     mats = _attn_weights(cfg) + _ffn_weights(cfg, False)
     f32 = 2 * d
-    if cfg["reference"] == "moe":
+    if "num_local_experts" in cfg:
         mats -= d * cfg["num_local_experts"]
         f32 += d * cfg["num_local_experts"]
     return layers * (2 * mats + 4 * f32) + 2 * v * d + 4 * d

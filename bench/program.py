@@ -19,12 +19,55 @@ import jax.numpy as jnp
 import weights as W
 
 
+#: The equations a configuration file states beyond its sizes: file key,
+#: the ``ModelConfig`` field it sets, and the value at which the equation
+#: is the plain decoder's. In the order ``model_config`` reads them.
+SCALARS = (
+    ("attention_multiplier", "query_scale", None),
+    ("embedding_multiplier", "embedding_multiplier", 1.0),
+    ("residual_multiplier", "residual_multiplier", 1.0),
+    ("logits_scaling", "logits_scaling", 1.0),
+)
+
+
+def _put(kw: Dict, cls, key: str, field: str, value, identity) -> None:
+    """Set ``field`` of ``cls`` to the file's ``value``; where ``cls`` has
+    no such field, only the identity can be run."""
+    if field in {f.name for f in dataclasses.fields(cls)}:
+        kw[field] = value
+    elif value != identity:
+        raise ValueError(f"{key}: the file asks for {field} = {value!r}, "
+                         f"and the program's {cls.__name__} has no such "
+                         "field")
+
+
 def model_config(cfg: Dict):
     """The program's ``ModelConfig`` for the configuration file ``cfg``:
     the registry entry's structure (layer kinds, norm and position
-    encoding) with every number of the file put in, so that the file holds
-    the configuration as it is run."""
+    encoding) with every number and equation of the file put in, so that
+    the file holds the configuration as it is run. A layer has experts
+    when the file has ``num_local_experts``.
+
+    The file's equations, and the program fields they set; a key the
+    file leaves out takes its identity value:
+
+    ========================  ==================================  =========
+    file key                  program field                       identity
+    ========================  ==================================  =========
+    attention_multiplier      ModelConfig.query_scale             None
+    embedding_multiplier      ModelConfig.embedding_multiplier    1.0
+    residual_multiplier       ModelConfig.residual_multiplier     1.0
+    logits_scaling            ModelConfig.logits_scaling          1.0
+    capacity_factor absent    MoEConfig.dropless = True           stated
+    ========================  ==================================  =========
+
+    Logits are divided by ``logits_scaling``. A value other than the
+    identity whose field the program's dataclass lacks raises
+    ``ValueError`` naming the file key; so does a registry entry whose
+    norm, positions, softcaps, embedding scale or vocabulary padding the
+    reference does not model."""
     from repro.configs import REGISTRY
+    from repro.configs.base import ModelConfig, MoEConfig
     base = REGISTRY[cfg["registry"]]
     layers = cfg["num_hidden_layers"]
     pattern = base.segments[0].pattern
@@ -38,20 +81,25 @@ def model_config(cfg: Dict):
         segments=(seg,), norm_eps=cfg["rms_norm_eps"],
         rope_theta=cfg["rope_theta"],
         tie_embeddings=cfg["tie_word_embeddings"])
-    if cfg["reference"] == "moe":
-        kw["moe"] = dataclasses.replace(
-            base.moe, n_experts=cfg["num_local_experts"],
-            top_k=cfg["num_experts_per_tok"],
-            d_ff_expert=cfg["intermediate_size"],
-            capacity_factor=cfg["capacity_factor"])
+    for key, field, identity in SCALARS:
+        _put(kw, ModelConfig, key, field, cfg.get(key, identity), identity)
+    if "num_local_experts" in cfg:
+        moe = dict(n_experts=cfg["num_local_experts"],
+                   top_k=cfg["num_experts_per_tok"],
+                   d_ff_expert=cfg["intermediate_size"])
+        if "capacity_factor" in cfg:
+            moe["capacity_factor"] = cfg["capacity_factor"]
+        _put(moe, MoEConfig, "capacity_factor", "dropless",
+             "capacity_factor" not in cfg, False)
+        kw["moe"] = dataclasses.replace(base.moe, **moe)
     out = dataclasses.replace(base, **kw)
-    if (out.norm, out.rope_mode, out.query_scale, out.attn_logit_softcap,
+    if (out.norm, out.rope_mode, out.attn_logit_softcap,
             out.final_logit_softcap, out.scale_embeddings,
-            out.vocab_pad_multiple) != ("rmsnorm", "rope", None, None, None,
+            out.vocab_pad_multiple) != ("rmsnorm", "rope", None, None,
                                         False, 1):
         raise ValueError(f"{cfg['registry']}: the reference does not "
                          "model this configuration's norm, positions, "
-                         "scaling or vocabulary padding")
+                         "softcaps, embedding scale or vocabulary padding")
     return out
 
 
@@ -62,7 +110,7 @@ def _to_program(cfg: Dict, w: Dict) -> Dict:
     top, lay = w["top"], w["layers"]
     ffn = ({"router": lay["router"], "w_gate": lay["w_gate"],
             "w_up": lay["w_up"], "w_down": lay["w_down"]}
-           if cfg["reference"] == "moe" else
+           if "num_local_experts" in cfg else
            {"w_gate": lay["w_gate"], "w_up": lay["w_up"],
             "w_down": lay["w_down"]})
     layer = {"pre_norm": {"scale": lay["attn_norm"] - 1.0},

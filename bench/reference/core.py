@@ -6,6 +6,18 @@ at a time, and runs every sequence of a batch over its whole length in
 float32 with ``HIGHEST`` matmul precision. Sequences are right-padded;
 with causal attention a padding position never reaches a real one.
 
+The configuration file states the decoder's scalars; a key it leaves out
+takes the plain decoder's value. Their names and places are those of
+IBM Granite's published forward pass (transformers ``GraniteMoe``,
+``modeling_granitemoe.py``; keys of the checkpoints' ``config.json``):
+
+- ``embedding_multiplier`` (1.0): token embeddings are multiplied by it;
+- ``attention_multiplier`` (absent: ``head_dim ** -0.5``): attention
+  scores are multiplied by it;
+- ``residual_multiplier`` (1.0): each sublayer adds its output times it
+  to the residual stream;
+- ``logits_scaling`` (1.0): the output logits are divided by it.
+
 ``control=True`` also runs the control beside it: the same forward with
 every matmul operand rounded to float8 e4m3 with a per-tensor scale, the
 next precision below the bfloat16 the configurations state.
@@ -62,7 +74,10 @@ def attention(cfg: Dict, w: Dict, h: jax.Array, low: bool) -> jax.Array:
     q, k = rope(q, cfg["rope_theta"]), rope(k, cfg["rope_theta"])
     g = hq // hkv   # query head i reads key/value head i // g
     k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-    sc = mm("nqhd,nkhd->nhqk", q, k, low) * hd ** -0.5
+    scale = cfg.get("attention_multiplier")
+    if scale is None:
+        scale = hd ** -0.5
+    sc = mm("nqhd,nkhd->nhqk", q, k, low) * scale
     causal = jnp.tril(jnp.ones((s, s), bool))
     p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
     o = mm("nhqk,nkhd->nqhd", p, v, low).reshape(n, s, hq * hd)
@@ -75,10 +90,10 @@ FFN = Callable[[Dict, Dict, jax.Array, jax.Array, int, bool], jax.Array]
 def _layer(cfg: Dict, ffn: FFN, x: jax.Array, w: Dict,
            prompt_len: jax.Array, chunk: int, low: bool) -> jax.Array:
     w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-    eps = cfg["rms_norm_eps"]
-    x = x + attention(cfg, w, rmsnorm(x, w["attn_norm"], eps), low)
-    return x + ffn(cfg, w, rmsnorm(x, w["ffn_norm"], eps), prompt_len,
-                   chunk, low)
+    eps, r = cfg["rms_norm_eps"], cfg.get("residual_multiplier", 1.0)
+    x = x + r * attention(cfg, w, rmsnorm(x, w["attn_norm"], eps), low)
+    return x + r * ffn(cfg, w, rmsnorm(x, w["ffn_norm"], eps), prompt_len,
+                       chunk, low)
 
 
 def _logits(cfg: Dict, top: Dict, x: jax.Array, at: jax.Array,
@@ -87,7 +102,7 @@ def _logits(cfg: Dict, top: Dict, x: jax.Array, at: jax.Array,
     h = jnp.take_along_axis(x, at[..., None], axis=1)     # (n, m, d)
     h = rmsnorm(h, top["final_norm"], cfg["rms_norm_eps"])
     head = top["embed"].T if cfg["tie_word_embeddings"] else top["lm_head"]
-    return mm("nmd,dv->nmv", h, head, low)
+    return mm("nmd,dv->nmv", h, head, low) / cfg.get("logits_scaling", 1.0)
 
 
 def forward(cfg: Dict, ffn: FFN, seed: int, tokens: np.ndarray,
@@ -106,7 +121,8 @@ def forward(cfg: Dict, ffn: FFN, seed: int, tokens: np.ndarray,
                    static_argnums=3)
     tokens, pl, at = jnp.asarray(tokens), jnp.asarray(prompt_len), \
         jnp.asarray(at)
-    x = top["embed"][tokens].astype(jnp.float32)
+    x = (top["embed"][tokens].astype(jnp.float32)
+         * cfg.get("embedding_multiplier", 1.0))
     xs = [x, x] if control else [x]
     for layer in range(cfg["num_hidden_layers"]):
         w = layer_w(key, layer)

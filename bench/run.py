@@ -5,10 +5,12 @@
 Everything is found by name: the cell in ``BENCHMARK.json``, its workload
 file ``bench/workloads/<cell>.json`` (serving settings and the
 correctness limit), its traffic mix ``bench/traffic/<traffic>.json``
-(read by ``bench/traffic/generate.py``), its configuration file, and one
-reader per metric in ``bench/metrics/<metric>.py``. The program is driven through its normal
-serving path: ``HyperOffloadSession(offload_config(...)).scheduler``,
-stepped by ``ContinuousScheduler.step()``.
+(read by ``bench/traffic/generate.py``), its configuration file, the
+plain reference the file names in ``bench/reference/<reference>.py``, and
+one reader per metric in ``bench/metrics/<metric>.py``. The program is
+driven through its normal serving path:
+``HyperOffloadSession(offload_config(...)).scheduler``, stepped by
+``ContinuousScheduler.step()``.
 
 A run: refuse anything but a TPU with the chips the cell asks for; draw
 the weights on the device from the seed; warm up every shape the cell's
@@ -38,7 +40,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
@@ -115,13 +116,24 @@ def load_cell(root: Path, name: str) -> Cell:
                 [m for m in bench["per_layer"] if _reports(m, name)])
 
 
-def reader(root: Path, name: str):
-    """The ``read`` function of ``bench/metrics/<name>.py`` under ``root``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metrics.{name}", path)
+def _module(root: Path, package: str, name: str):
+    """``bench/<package>/<name>.py`` under ``root``, loaded by its path."""
+    path = root / "bench" / package / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"{package}.{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def reader(root: Path, name: str):
+    """The ``read`` function of ``bench/metrics/<name>.py`` under ``root``."""
+    return _module(root, "metrics", name).read
+
+
+def reference(root: Path, cfg: Dict):
+    """The plain reference that the configuration file names: the module
+    ``bench/reference/<cfg["reference"]>.py`` under ``root``."""
+    return _module(root, "reference", cfg["reference"])
 
 
 # ---------------------------------------------------------------------------
@@ -410,15 +422,16 @@ def sample(outputs: Dict[int, Tuple[np.ndarray, List[int]]], longest: int,
 
 
 def logit_gaps(cfg: Dict, seed: int, outputs, picked: List[int], *,
-               chunk: int, control: bool = False) -> Dict[str, float]:
-    """Run the reference once over each picked prompt with its served
-    tokens. At each served position the gap is how far the served token's
+               chunk: int, control: bool = False,
+               root: Path = ROOT) -> Dict[str, float]:
+    """Run the reference that ``cfg`` names (under ``root``) once over each
+    picked prompt with its served tokens. At each served position the gap is how far the served token's
     logit lies below the reference's best: ``max_logit_gap`` is the widest
     and ``mean_logit_gap`` the mean over every served token compared.
     With ``control``, also ``control_max_logit_gap`` and
     ``control_mean_logit_gap``: the same for the token the control puts
     first at each of those positions."""
-    ref = importlib.import_module(f"reference.{cfg['reference']}")
+    ref = reference(root, cfg)
     served_gaps, control_gaps = [], []
     for g in range(0, len(picked), REF_GROUP):
         group = picked[g:g + REF_GROUP]
@@ -555,7 +568,8 @@ def run(args: argparse.Namespace, root: Path = ROOT) -> Dict[str, Any]:
     t_ref = time.perf_counter()
     picked = sample(served.outputs, served.longest, args.seed)
     gaps = logit_gaps(cell.config, args.seed, served.outputs, picked,
-                      chunk=cell.workload["serving"]["chunk_size"])
+                      chunk=cell.workload["serving"]["chunk_size"],
+                      root=root)
     print(f"reference: {sum(len(served.outputs[r][1]) for r in picked)} "
           f"served tokens of {len(picked)} requests compared in "
           f"{time.perf_counter() - t_ref:.1f} s", file=sys.stderr)

@@ -33,6 +33,9 @@ TINY_CONFIG = {
                                  num_local_experts=8, num_experts_per_tok=8,
                                  vocab_size=512),
 }
+#: the widths and depth a CPU test runs ``granite_published`` at: two
+#: layers, its 40 experts
+TINY_GRANITE = dict(TINY_CONFIG["granite-moe-3b-a800m"], num_local_experts=40)
 TINY_ROUTED = dict(num_local_experts=16, num_experts_per_tok=8,
                    capacity_factor=1.25)
 #: serving settings a CPU test runs at, per cell
@@ -49,6 +52,21 @@ TINY_OUTPUT = {"dist": "uniform", "lo": 8, "hi": 24}
 #: float8 control's at least 0.038; the program's mean gap at most 9e-5
 #: and the control's at least 2.8e-3. The planted faults read far above.
 TINY_LIMITS = {"max_logit_gap": 0.02, "mean_logit_gap": 5e-4}
+
+
+def load_config(name: str) -> dict:
+    """The benchmark's configuration file ``bench/configs/<name>.json``."""
+    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+def granite_published() -> dict:
+    """Granite 3.0 3B-A800M as published (``data/``): the four scalars of
+    its forward pass and dropless routing, with no ``capacity_factor``.
+    The program does not run it yet: weights, counts and the reference
+    take it, and ``program.model_config`` refuses it by the first key
+    that the program cannot run."""
+    return json.loads((BENCH / "tests" / "data"
+                       / "granite-3.0-3b-a800m-published.json").read_text())
 
 
 def _edit(path: Path, **changes) -> None:
@@ -125,16 +143,19 @@ def tiny_root(tmp_path: Path) -> Path:
 
 
 @pytest.fixture
-def on_cpu(monkeypatch):
+def on_cpu(monkeypatch, tmp_path):
     """Let ``run`` drive the CPU: skip the look for a TPU and give the
     CPU the v5e's peaks, so that per-layer arithmetic has a peak to
-    divide by. Nothing from such a run is a device measurement."""
+    divide by. Nothing from such a run is a device measurement. Each test
+    traces into a directory of its own, so that traced runs in parallel
+    workers do not read or delete each other's profiles."""
     import jax
     from repro.launch import compile_cache
 
     import peaks
     import run
     monkeypatch.setattr(run, "require_tpu", lambda chips: jax.devices()[0])
+    monkeypatch.setattr(run, "TRACE_DIR", tmp_path / "trace")
     # CPU programs stay out of the checkout's persistent compile cache
     monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
     v5e = peaks.lookup("TPU v5 lite")

@@ -1,5 +1,6 @@
 """``bench/flops.py`` against counts made by hand."""
 
+import conftest
 import flops
 
 DENSE = dict(reference="dense", hidden_size=8, intermediate_size=16,
@@ -85,3 +86,36 @@ def test_model_step_metrics_read_nothing_without_the_programs():
     ctx = _ctx({"jit_decode_step": 1.0}, steps)
     ctx.trace = None
     assert run.reader(run.ROOT, "step_mfu")(ctx) is None
+
+
+#: counts of the held files at full size, as the benchmark has made them
+#: since its first cells: token_flops at context 1 and 768, head_flops,
+#: decode_weight_bytes, kv_bytes_per_token
+HELD_COUNTS = {
+    "phi3-mini-3.8b": (7248150528, 7549747200, 197001216, 7445557248,
+                       393216),
+    "granite-moe-3b-a800m": (1614741504, 1765539840, 151004160,
+                             6601718784, 65536),
+}
+
+
+def _counts(cfg):
+    return (flops.token_flops(cfg, 1), flops.token_flops(cfg, 768),
+            flops.head_flops(cfg), flops.decode_weight_bytes(cfg),
+            flops.kv_bytes_per_token(cfg))
+
+
+def test_held_files_keep_their_counts():
+    for name, want in HELD_COUNTS.items():
+        assert _counts(conftest.load_config(name)) == want, name
+
+
+def test_granite_as_published_counts_router_and_top_8_experts():
+    cfg = conftest.granite_published()
+    # per layer: wq 1536x1536 + wo 1536x1536 + wk, wv 1536x512 each;
+    # router 1536 x 40; 8 of the 40 experts, each 3 x 1536 x 512
+    attn = 2 * 1536 * 1536 + 2 * 1536 * 512
+    ffn = 1536 * 40 + 8 * 3 * 1536 * 512
+    assert flops.token_flops(cfg, 1) == 32 * (2 * (attn + ffn)
+                                              + 4 * 24 * 64)
+    assert _counts(cfg) == HELD_COUNTS["granite-moe-3b-a800m"]

@@ -92,6 +92,44 @@ def test_discovers_added_cell_and_metric_without_edits(tiny_root, on_cpu):
     assert res["metrics"]["throwaway_steps"]["value"] > 0
 
 
+def test_discovers_added_configuration_and_reference_without_edits(
+        tiny_root, on_cpu):
+    """A configuration file that names a reference module of its own, new
+    beside it, and has experts: weights, program, counts and the
+    comparison take it from its keys and its name alone."""
+    import flops
+    import weights as W
+    bench_dir = tiny_root / "bench"
+    cfg = json.loads((bench_dir / "configs"
+                      / "granite-moe-3b-a800m.json").read_text())
+    cfg.update(name="throwaway-moe", reference="throwaway_moe")
+    (bench_dir / "configs" / "throwaway-moe.json").write_text(
+        json.dumps(cfg))
+    (bench_dir / "reference" / "throwaway_moe.py").write_text(
+        "from reference.moe import ffn, forward  # noqa: F401\n")
+    bench = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    bench["configs"].append(dict(conftest.HELD_CONFIG, name="throwaway-moe",
+                                 file="bench/configs/throwaway-moe.json"))
+    bench["workloads"].append(dict(conftest.HELD_CELL, name="throwaway-cell",
+                                   config="throwaway-moe"))
+    (tiny_root / "BENCHMARK.json").write_text(json.dumps(bench))
+    wl = json.loads((bench_dir / "workloads"
+                     / f"{conftest.HELD_CELL['name']}.json").read_text())
+    (bench_dir / "workloads" / "throwaway-cell.json").write_text(
+        json.dumps(dict(wl, config="throwaway-moe")))
+    assert "router" in W.layer_spec(cfg)
+    # the experts a token takes count as one dense feed-forward as wide
+    # as all of them, plus the router
+    e, k, d, f = (cfg["num_local_experts"], cfg["num_experts_per_tok"],
+                  cfg["hidden_size"], cfg["intermediate_size"])
+    dense = {key: v for key, v in cfg.items() if key != "num_local_experts"}
+    dense["intermediate_size"] = k * f
+    assert flops.token_flops(cfg, 1) == flops.token_flops(dense, 1) + \
+        2 * cfg["num_hidden_layers"] * d * e
+    res = _run(on_cpu, tiny_root, "throwaway-cell")
+    assert res["correct"], res["checks"]
+
+
 def _break_decode(monkeypatch, wrap):
     from repro.sched import scheduler
     real = scheduler.jit_decode

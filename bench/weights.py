@@ -32,7 +32,9 @@ def seed_key(seed: int) -> jax.Array:
 
 
 def layer_spec(cfg: Dict) -> Spec:
-    """The leaves of one decoder layer, in drawing order."""
+    """The leaves of one decoder layer, in drawing order: a feed-forward
+    of experts where the file has ``num_local_experts``, else a dense
+    one."""
     d, hd = cfg["hidden_size"], cfg["head_dim"]
     hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     f = cfg["intermediate_size"]
@@ -44,7 +46,7 @@ def layer_spec(cfg: Dict) -> Spec:
         "wo": ((hq * hd, d), BF16, "fan_in", 0),
         "ffn_norm": ((d,), F32, "norm", 0),
     }
-    if cfg["reference"] == "moe":
+    if "num_local_experts" in cfg:
         e = cfg["num_local_experts"]
         spec.update({
             "router": ((d, e), F32, "fan_in", 0),
