@@ -18,10 +18,21 @@ traffic uses (for a backlog, fill the batch until every row decodes);
 open the window and submit each request as its due time passes, stamping
 every token with the time its step returned; close the window; with
 ``--trace 1`` the profiler traces the window and the per-layer metrics
-are read instead of the end-to-end ones. Then the program is freed and a
-sample of the served requests is compared with the float32 reference
-(``bench/reference``): the widest gap by which a served token's logit
-lies below the reference's best must stay under the workload's limit.
+are read instead of the end-to-end ones.
+
+A metric's reader takes a ``Reading`` (below): the window and its steps,
+the program's spans, its counters over the window (every numeric leaf of
+``session.stats()`` by dotted path, as ``sched.steps``: read them with
+``metrics/_counters.delta``), and in a traced run the trace reduction,
+with device seconds by compiled program (``metrics/_model_step``) and by
+``jax.named_scope`` or Pallas kernel name (``metrics/_scope.device_s``).
+A configuration's kernel reader needs only its own operation and byte
+counts beside these.
+
+After the window the program is freed and a sample of the served
+requests is compared with the float32 reference (``bench/reference``):
+the widest gap by which a served token's logit lies below the
+reference's best must stay under the workload's limit.
 
 The last line of standard output is the result as one JSON object; the
 numbers compared, each beside its limit, are the last lines of standard
@@ -193,7 +204,8 @@ class Served:
     window: endtoend.Window
     steps: List[StepWork]           # steps that ended inside the window
     spans: List[Tuple[str, Tuple[float, float]]]
-    transfer_bytes: Dict[str, int]
+    counters: Dict[str, float]
+    stepped: int                    # steps taken between their reads
     outputs: Dict[int, Tuple[np.ndarray, List[int]]]   # prompt, tokens
     longest: int
     compiles_in_window: int
@@ -328,7 +340,7 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, dev,
         pending = list(items)
     jax.block_until_ready(sched.cache)
 
-    pairs0 = _transfer_bytes(session)
+    stats0 = counters(session.stats())
     if trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         # host annotations (the marker) without Python function events
@@ -345,7 +357,7 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, dev,
     setup_s = t_open - T_START
     t_close, due = open_loop(drv, pending, t_open, seconds)
     compiles = clock.count - compiles0
-    pairs1 = _transfer_bytes(session)
+    stats1 = counters(session.stats())
     window_steps = drv.steps[n_warm_steps:]
     if trace:
         jax.profiler.stop_trace()
@@ -383,8 +395,8 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, dev,
         window=window, steps=[s for s in window_steps
                               if window.inside(s.t_end)],
         spans=spans,
-        transfer_bytes={k: pairs1.get(k, 0) - pairs0.get(k, 0)
-                        for k in pairs1},
+        counters={k: v - stats0.get(k, 0.0) for k, v in stats1.items()},
+        stepped=len(window_steps),
         outputs=outputs,
         longest=max(outputs, key=lambda r: len(outputs[r][1])),
         compiles_in_window=compiles, setup_s=setup_s, peak_bytes=peak,
@@ -395,9 +407,26 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, dev,
     return served
 
 
-def _transfer_bytes(session) -> Dict[str, int]:
-    pairs = session.stats()["pool"]["transfer"]["pairs"]
-    return {k: int(v["bytes"]) for k, v in pairs.items()}
+def counters(stats: Dict[str, Any], prefix: str = "") -> Dict[str, float]:
+    """Every numeric leaf of ``stats`` (``session.stats()``) under its
+    dotted path: ``sched.steps``, ``pool.transfer.pairs.host->device.bytes``,
+    ``telemetry.histograms.histograms.req_ttft_seconds.count``."""
+    out: Dict[str, float] = {}
+    for key, value in stats.items():
+        name = f"{prefix}{key}"
+        if isinstance(value, dict):
+            out.update(counters(value, f"{name}."))
+        elif isinstance(value, (int, float)) and not isinstance(value, bool):
+            out[name] = float(value)
+    return out
+
+
+def transfer_bytes(deltas: Dict[str, float]) -> Dict[str, int]:
+    """Bytes the pool moved over the window, by tier pair
+    (``device->host``), from the counters' changes."""
+    head, tail = "pool.transfer.pairs.", ".bytes"
+    return {k[len(head):-len(tail)]: int(v) for k, v in deltas.items()
+            if k.startswith(head) and k.endswith(tail)}
 
 
 # ---------------------------------------------------------------------------
@@ -492,10 +521,35 @@ def _gap_stats(prefix: str, gaps: np.ndarray) -> Dict[str, float]:
 
 @dataclasses.dataclass
 class Reading:
-    """What a metric's reader reads (``bench/metrics``)."""
+    """What a metric's reader (``bench/metrics/<metric>.py``) reads:
+
+    - ``window``: its open and close on the host clock, every token's
+      time and every request's due time (``endtoend.Window``);
+    - ``steps``: each scheduler step that ended in the window, with the
+      context lengths it prefilled and decoded and the tokens it emitted;
+    - ``spans``: the program's ``obs`` spans (name, host interval) that
+      ended in the window; traced runs only;
+    - ``counters``: the change over the window of every numeric leaf of
+      ``session.stats()`` by its dotted path (``sched.steps``,
+      ``sched.decoded_tokens``, ``pool.transfer.pairs.host->device.bytes``;
+      with ``--trace 1`` also the histograms, as
+      ``telemetry.histograms.histograms.req_ttft_seconds.count`` and
+      ``.sum``), read before the window opens and after it closes
+      (``metrics/_counters.py``); ``transfer_bytes``: the pool's bytes
+      moved by tier pair, from the same counters;
+    - ``cfg``: the configuration file; ``peaks``: the chip's peaks
+      (``peaks.json``);
+    - ``trace``: with ``--trace 1`` on a TPU, the trace reduction
+      (``trace_reduce.Reduced``): busy and idle time, device seconds by
+      compiled program (``module_s``, ``metrics/_model_step.py``) and by
+      ``jax.named_scope`` or Pallas kernel name (``scope_s``,
+      ``metrics/_scope.py``); None otherwise;
+    - ``peak_bytes``: the fullest chip's peak memory; ``setup_s``.
+    """
     window: endtoend.Window
     steps: List[StepWork]
     spans: List[Tuple[str, Tuple[float, float]]]
+    counters: Dict[str, float]
     transfer_bytes: Dict[str, int]
     cfg: Dict
     peaks: Dict[str, float]
@@ -539,8 +593,9 @@ def run(args: argparse.Namespace, root: Path = ROOT) -> Dict[str, Any]:
     served = serve(cell, args.seed, args.seconds, trace, dev, clock)
 
     ctx = Reading(served.window, served.steps, served.spans,
-                  served.transfer_bytes, cell.config, peaks, served.trace,
-                  served.peak_bytes, served.setup_s)
+                  served.counters, transfer_bytes(served.counters),
+                  cell.config, peaks, served.trace, served.peak_bytes,
+                  served.setup_s)
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in wanted:
@@ -558,6 +613,9 @@ def run(args: argparse.Namespace, root: Path = ROOT) -> Dict[str, Any]:
           f"due, setup {served.setup_s:.3f} s "
           f"(compile {clock.seconds:.3f} s), compiles in window "
           f"{served.compiles_in_window}", file=sys.stderr)
+    print(f"counters: sched.steps {served.counters.get('sched.steps')} "
+          f"(steps taken {served.stepped}), sched.decoded_tokens "
+          f"{served.counters.get('sched.decoded_tokens')}", file=sys.stderr)
     ttft = endtoend.ttft_ms(served.window)
     if ttft:
         q = np.percentile(ttft, [50, 75, 90, 95, 100])

@@ -50,7 +50,7 @@ def _ctx(module_s, steps):
     import types
 
     import trace_reduce
-    trace = trace_reduce.Reduced(1.0, 10.0, [], [], module_s)
+    trace = trace_reduce.Reduced(1.0, 10.0, [], [], module_s, {})
     return types.SimpleNamespace(
         cfg=DENSE, steps=steps, trace=trace,
         peaks={"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11})

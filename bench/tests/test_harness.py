@@ -174,3 +174,76 @@ def test_control_is_not_correct(tiny_root, on_cpu, cell):
                           control=True)
     for name, limit in c.workload["limits"].items():
         assert gaps[name] <= limit < gaps[f"control_{name}"]
+
+
+class _ScriptedStats:
+    """A session whose ``stats()`` returns ``snapshots`` in turn and which
+    is otherwise the real one."""
+
+    def __init__(self, real, snapshots):
+        self._real, self._snapshots = real, list(snapshots)
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+    def stats(self):
+        return self._snapshots.pop(0)
+
+
+def test_counters_are_after_minus_before(tiny_root, on_cpu, monkeypatch):
+    """``Reading.counters``: every numeric leaf of ``stats()`` by its
+    dotted path, read before the window opens and after it closes, as
+    the difference; flags and names are left out."""
+    import program
+    run = on_cpu
+    before = {"sched": {"steps": 5, "dropless": True, "mode": "x"},
+              "pool": {"transfer": {"pairs": {"device->host": {
+                  "bytes": 10}}}},
+              "prefix": None, "gone": 2}
+    after = {"sched": {"steps": 12, "dropless": True, "mode": "x"},
+             "pool": {"transfer": {"pairs": {"device->host": {"bytes": 25},
+                                             "host->device": {"bytes": 4}}}},
+             "prefix": None, "new": 3.5}
+    real = program.session
+    monkeypatch.setattr(program, "session", lambda *a, **k: _ScriptedStats(
+        real(*a, **k), [before, after]))
+    c = run.load_cell(tiny_root, "phi3-resident-chat")
+    served = run.serve(c, SEED, 1.0, False, jax.devices()[0],
+                       run.CompileClock())
+    assert served.counters == {
+        "sched.steps": 7.0, "pool.transfer.pairs.device->host.bytes": 15.0,
+        "pool.transfer.pairs.host->device.bytes": 4.0, "new": 3.5}
+    assert run.transfer_bytes(served.counters) == {"device->host": 15,
+                                                  "host->device": 4}
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_counters_count_the_window_steps(tiny_root, on_cpu, trace):
+    """The scheduler's own step counter moves by the steps the harness took
+    between the two reads; with telemetry on, the histograms come too."""
+    run = on_cpu
+    c = run.load_cell(tiny_root, "phi3-resident-chat")
+    served = run.serve(c, SEED, 1.0, bool(trace), jax.devices()[0],
+                       run.CompileClock())
+    assert served.stepped > 0
+    assert served.counters["sched.steps"] == served.stepped
+    assert served.counters["sched.decoded_tokens"] > 0
+    hist = "telemetry.histograms.histograms.req_ttft_seconds"
+    assert (f"{hist}.count" in served.counters) == bool(trace)
+    assert (f"{hist}.sum" in served.counters) == bool(trace)
+
+
+def test_scope_and_counter_readers():
+    import types
+
+    import trace_reduce
+    from metrics import _counters, _scope
+    trace = trace_reduce.Reduced(1.0, 10.0, [], [], {},
+                                 {"experts": 0.25, "idle_scope": 0.0})
+    ctx = types.SimpleNamespace(trace=trace, counters={"sched.steps": 7.0})
+    assert _scope.device_s(ctx, "experts") == 0.25
+    assert _scope.device_s(ctx, "idle_scope") is None
+    assert _scope.device_s(ctx, "absent") is None
+    assert _scope.device_s(types.SimpleNamespace(trace=None), "x") is None
+    assert _counters.delta(ctx, "sched.steps") == 7.0
+    assert _counters.delta(ctx, "sched.absent") is None

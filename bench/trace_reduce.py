@@ -1,5 +1,6 @@
 """Reduce a JAX profiler trace of the measured window to device busy time,
-idle share, the costliest device operations and the longest idle gaps.
+idle share, the costliest device operations, the longest idle gaps, and
+device time by compiled program and by ``jax.named_scope``.
 
 The window and the program's ``obs`` spans are on the host's
 ``time.perf_counter`` clock; the trace has its own. The harness opens a
@@ -12,8 +13,24 @@ Busy time on a device is the union of the intervals of its operations
 Device time is also summed per compiled program of the ``XLA Modules``
 line, named without its hash (``jit_decode_step``, ``jit_scatter``):
 the per-layer metrics of the model step divide by the time of the
-model's own programs. Each idle gap is named by the ``obs`` phase that
-covers most of it, or ``between_steps`` where none does.
+model's own programs.
+
+Device time by scope: on a TPU the profile carries an op's
+``jax.named_scope`` path in the ``tf_op`` stat of the op's event
+metadata (``jit(step)/outer/inner/dot_general:``); the device plane has
+no name-scope line, and ``jax.profiler.ProfileData`` does not expose
+metadata stats, so the file is read with ``bench/xplane.py``. Every name
+on the path but the last (the operation itself) is a scope of the op,
+the jitted function's ``jit(...)`` among them; a Pallas kernel's path
+ends in ``<kernel name>/pallas_call``, so the kernel's ``name`` is a
+scope of its op (``tests/data/scoped_tpu.*``). An op counts toward every
+scope on its path; a scope's seconds are the union of its ops' intervals
+on each device, clipped to the window, so overlapping ops count once. A
+fusion takes the path of its root op.
+
+The longest idle gaps are named by the ``obs`` phase that covers most of
+each, or ``between_steps`` where none does; only the gaps reported are
+named.
 """
 
 from __future__ import annotations
@@ -24,9 +41,13 @@ import os
 from collections import defaultdict
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+import xplane
+
 MARKER = "bench.clock"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+#: the event metadata stat that holds an op's name-scope path
+SCOPE_STAT = "tf_op"
 #: the scheduler's step phases an idle gap is attributed to
 PHASES = ("admit_prefill", "collect", "decode", "park_issue")
 
@@ -49,6 +70,8 @@ class Op:
     start: float      # trace seconds
     end: float
     module: bool      # a whole compiled program, not one of its ops
+    #: the named scopes the op ran under, outermost first
+    scopes: Tuple[str, ...] = ()
 
 
 def latest_xplane(trace_dir: str) -> str:
@@ -64,27 +87,44 @@ def module_name(name: str) -> str:
     return name.split("(", 1)[0]
 
 
+def scope_path(tf_op: str) -> Tuple[str, ...]:
+    """``jit(step)/outer/inner/dot_general:`` -> ``("jit(step)", "outer",
+    "inner")``: the scopes of an op, without the op itself."""
+    return tuple(tf_op.rsplit(":", 1)[0].split("/")[:-1])
+
+
 def read_trace(path: str, marker_perf_s: float) -> Tuple[Clock, List[Op]]:
-    """(clock, device ops and programs) of the trace at ``path``."""
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
+    """(clock, device ops and programs) of the trace at ``path``. Times
+    are whole nanoseconds, as ``jax.profiler.ProfileData`` gives them."""
     marker = None
     ops: List[Op] = []
-    for plane in pd.planes:
-        if plane.name.startswith("/host:"):
+    for plane in xplane.read(path).planes:
+        if plane.name.startswith("/host:") and marker is None:
+            ids = {e.key for e in plane.event_metadata
+                   if e.value.name == MARKER}
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name == MARKER and marker is None:
-                        marker = ev.start_ns * 1e-9
+                    if ev.metadata_id in ids:
+                        marker = (line.timestamp_ns + ev.offset_ps // 1000) \
+                            * 1e-9
+                        break
+                if marker is not None:
+                    break
         elif plane.name.startswith("/device:TPU"):
+            names = {e.key: e.value.name for e in plane.event_metadata}
+            scopes = {k: scope_path(v) for k, v in
+                      xplane.stat_strings(plane, SCOPE_STAT).items()}
             for line in plane.lines:
                 if line.name not in (OPS_LINE, MODULES_LINE):
                     continue
+                module = line.name == MODULES_LINE
                 for ev in line.events:
-                    s = ev.start_ns * 1e-9
-                    ops.append(Op(plane.name, ev.name, s,
-                                  s + ev.duration_ns * 1e-9,
-                                  line.name == MODULES_LINE))
+                    s = (line.timestamp_ns + ev.offset_ps // 1000) * 1e-9
+                    mid = ev.metadata_id
+                    ops.append(Op(plane.name, names[mid], s,
+                                  s + (ev.duration_ps // 1000) * 1e-9,
+                                  module, () if module
+                                  else scopes.get(mid, ())))
     if marker is None:
         raise ValueError(f"marker {MARKER!r} not found in {path}")
     return Clock(marker - marker_perf_s), ops
@@ -98,12 +138,6 @@ def union(intervals: Iterable[Interval]) -> List[Interval]:
         else:
             out.append((s, e))
     return out
-
-
-def clip(intervals: Sequence[Interval], lo: float,
-         hi: float) -> List[Interval]:
-    return [(max(s, lo), min(e, hi)) for s, e in intervals
-            if e > lo and s < hi]
 
 
 def gaps(busy: Sequence[Interval], lo: float, hi: float) -> List[Interval]:
@@ -138,10 +172,18 @@ class Reduced:
     idle_gaps: List[Tuple[str, float]]
     #: device seconds of each compiled program, mean over those devices
     module_s: Dict[str, float]
+    #: device seconds of the ops under each named scope, mean over those
+    #: devices
+    scope_s: Dict[str, float]
 
     @property
     def idle_share(self) -> float:
         return 1.0 - self.busy_s / self.window_s
+
+
+def _covered(by_plane: Dict[str, List[Interval]]) -> float:
+    """Seconds covered by the intervals, summed over the planes."""
+    return sum(sum(e - s for s, e in union(ivs)) for ivs in by_plane.values())
 
 
 def reduce(clock: Clock, ops: Sequence[Op], window: Interval,
@@ -151,26 +193,32 @@ def reduce(clock: Clock, ops: Sequence[Op], window: Interval,
     (phase, perf_counter interval). None where no device ran an op."""
     lo, hi = clock.to_trace(window[0]), clock.to_trace(window[1])
     by_plane: Dict[str, List[Interval]] = defaultdict(list)
+    by_scope: Dict[str, Dict[str, List[Interval]]] = defaultdict(
+        lambda: defaultdict(list))
     time_by_op: Dict[str, float] = defaultdict(float)
     for op in ops:
-        iv = clip([(op.start, op.end)], lo, hi)
-        if not iv:
+        if op.end <= lo or op.start >= hi:
             continue
+        iv = (max(op.start, lo), min(op.end, hi))
         if op.module:
-            time_by_op[module_name(op.name)] += iv[0][1] - iv[0][0]
-        else:
-            by_plane[op.plane].append(iv[0])
+            time_by_op[module_name(op.name)] += iv[1] - iv[0]
+            continue
+        by_plane[op.plane].append(iv)
+        for scope in op.scopes:
+            by_scope[scope][op.plane].append(iv)
     if not by_plane:
         return None
     merged = {p: union(ivs) for p, ivs in by_plane.items()}
-    busy = sum(sum(e - s for s, e in m) for m in merged.values()) \
-        / len(merged)
-    traced_spans = [(n, (clock.to_trace(a), clock.to_trace(b)))
-                    for n, (a, b) in spans if n in PHASES]
+    n = len(merged)
+    busy = sum(sum(e - s for s, e in m) for m in merged.values()) / n
+    traced_spans = [(name, (clock.to_trace(a), clock.to_trace(b)))
+                    for name, (a, b) in spans if name in PHASES]
     first = min(merged)  # the first device's gaps stand for the step's
-    idle = sorted(((name_gap(g, traced_spans), g[1] - g[0])
-                   for g in gaps(merged[first], lo, hi)),
-                  key=lambda kv: -kv[1])[:top]
-    module_s = {k: v / len(merged) for k, v in time_by_op.items()}
+    # longest first, ties in time order; only those reported are named
+    longest = sorted(gaps(merged[first], lo, hi),
+                     key=lambda g: -(g[1] - g[0]))[:top]
+    idle = [(name_gap(g, traced_spans), g[1] - g[0]) for g in longest]
+    module_s = {k: v / n for k, v in time_by_op.items()}
     ops_top = sorted(module_s.items(), key=lambda kv: -kv[1])[:top]
-    return Reduced(busy, hi - lo, ops_top, idle, module_s)
+    scope_s = {k: _covered(v) / n for k, v in by_scope.items()}
+    return Reduced(busy, hi - lo, ops_top, idle, module_s, scope_s)
