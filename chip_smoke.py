@@ -67,7 +67,7 @@ HOST_BACKEND = "jax-host[pinned_host]"
 # to 2**-9 each. Flash's early causal rows attend over a few keys, where
 # that moves an output by up to about 2**-8 of max|v| (max|v| is near 4
 # for these standard-normal draws); on a v5e it errs by 1.6e-2. The
-# decode kernels average 400-700 keys, so the roundings cancel, and on a
+# decode kernels average 256-700 keys, so the roundings cancel, and on a
 # v5e they err by at most 2.0e-3; 2**-8 is twice that. Losing or gaining
 # one key (ring ``pos`` or paged ``tail_len`` off by one) moves their
 # outputs by 1.8e-2 to 5.5e-2 over seeds 0-2, past the decode limit by at
@@ -172,12 +172,13 @@ def serve_mode(mode: str, model, params, dtype: str, trace, dev: jax.Device,
     return out
 
 
-def _check_kernel(name: str, args, want: jax.Array, scale: float) -> None:
+def _check_kernel(name: str, args, want: jax.Array, scale: float,
+                  **kw) -> None:
     """Run ``ops.<name>`` on ``args``; its compiled program must hold the
     Mosaic kernel and its output must match ``want``."""
     fn = getattr(ops, name)
-    out = fn(*args, scale=scale)
-    hlo = fn.lower(*args, scale=scale).compile().as_text()
+    out = fn(*args, scale=scale, **kw)
+    hlo = fn.lower(*args, scale=scale, **kw).compile().as_text()
     check("tpu_custom_call" in hlo, f"{name}: no Mosaic kernel in program")
     check(out.shape == want.shape, f"{name}: shape {out.shape}")
     out = np.asarray(out, np.float32)
@@ -206,15 +207,21 @@ def kernel_phase() -> None:
             scale=scale).transpose(0, 2, 1, 3)
     _check_kernel("flash_attention", (q, k, v), want, scale)
 
-    # ring decode: batch 4 over a max_seq-slot ring, 701 tokens written
-    q = normal((4, 1, h, d))
-    k, v = normal((4, MAX_SEQ, h, d)), normal((4, MAX_SEQ, h, d))
-    pos = jnp.int32(700)
+    # ring decode: layer 1 of a two-layer stacked cache, batch 4 over
+    # max_seq-slot rings at lengths that end inside, on and past a block
+    q = normal((4, h * d))
+    k, v = normal((2, 4, MAX_SEQ, h * d)), normal((2, 4, MAX_SEQ, h * d))
+    pos = jnp.asarray([700, 300, 255, 256], jnp.int32)
+
+    def heads(x):
+        return x[1].reshape(4, MAX_SEQ, h, d).transpose(0, 2, 1, 3)
+
     with jax.default_matmul_precision("highest"):
         want = ref.decode_attention_ref(
-            q[:, 0], k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), pos,
-            scale=scale)[:, None]
-    _check_kernel("decode_attention", (q, k, v, pos), want, scale)
+            q.reshape(4, h, d), heads(k), heads(v), pos,
+            scale=scale).reshape(4, h * d)
+    _check_kernel("decode_attention", (q, k, v, jnp.int32(1), pos), want,
+                  scale, head_dim=d)
 
     # paged decode: 12 scrambled pages of a 16-slot buffer + a 19-token tail
     q = normal((4, h, d))

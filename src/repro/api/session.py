@@ -162,6 +162,7 @@ class HyperOffloadSession:
                  "decoded_tokens": 0, "pages_parked": 0, "cold_spills": 0,
                  "prefix_hits": 0, "prefix_hit_tokens": 0,
                  "preemptions": 0, "resumes": 0, "shed": 0,
+                 "decode_kv_slots_read": 0, "decode_kv_slots_ring": 0,
                  "admission_blocked": 0}
         prefetch = {"steps": 0, "fetches_issued": 0, "layers_planned": 0}
         slo: Optional[Dict[str, int]] = None
@@ -170,7 +171,8 @@ class HyperOffloadSession:
             for k in ("steps", "joins", "retires", "prefill_tokens",
                       "prefill_chunks", "decoded_tokens", "pages_parked",
                       "cold_spills", "prefix_hits", "prefix_hit_tokens",
-                      "preemptions", "resumes", "shed"):
+                      "preemptions", "resumes", "shed",
+                      "decode_kv_slots_read", "decode_kv_slots_ring"):
                 sched[k] += getattr(s.stats, k)
             sched["admission_blocked"] += s.admission.blocked
             snap = s.slo_snapshot()

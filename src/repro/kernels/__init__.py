@@ -1,8 +1,10 @@
 """Pallas TPU kernels for the perf-critical compute layers.
 
 - flash_attention — prefill attention (GQA, sliding window, logit softcap)
-- paged_attention — ring-cache decode attention (the HyperOffload serving
-  hot path: consumes pool-prefetched KV blocks tile-by-tile)
+- decode_attention — decode over the stacked ring cache, reading only
+  each row's live kv blocks (the scheduler's decode on a TPU)
+- paged_attention — paged decode attention (consumes pool-prefetched KV
+  pages tile-by-tile)
 - ssd_scan       — Mamba2 SSD chunked scan with VMEM state carry
 
 Each has a jit wrapper in ``ops`` and a pure-jnp oracle in ``ref``;

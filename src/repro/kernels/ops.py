@@ -14,10 +14,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.paged_attention import (
-    decode_attention_pallas, paged_decode_attention_pallas,
+from repro.kernels.decode_attention import (
+    decode_attention_pallas, ring_lengths,
 )
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.paged_attention import paged_decode_attention_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
@@ -42,18 +43,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "logit_cap"))
+@functools.partial(jax.jit,
+                   static_argnames=("head_dim", "scale", "logit_cap"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     pos: jax.Array, *, scale: float,
+                     layer: jax.Array, pos: jax.Array, *, head_dim: int,
+                     scale: float,
                      logit_cap: Optional[float] = None) -> jax.Array:
-    """Ring-cache decode attention: q (B,1,Hq,D), cache (B,C,Hkv,D) →
-    (B,1,Hq,D)."""
-    q3 = q[:, 0]                              # (B, Hq, D)
-    kt = k.transpose(0, 2, 1, 3)              # (B, Hkv, C, D)
-    vt = v.transpose(0, 2, 1, 3)
-    out = decode_attention_pallas(q3, kt, vt, pos, scale=scale,
-                                  logit_cap=logit_cap, interpret=_interpret())
-    return out[:, None]
+    """Decode attention over layer ``layer`` of a stacked ring cache:
+    q (B, Hq·D), cache (L, B, C, Hkv·D), ``pos`` the index each row just
+    wrote (scalar or (B,); -1 for an empty row) → (B, Hq·D) float32."""
+    pos = jnp.broadcast_to(pos, (q.shape[0],)).astype(jnp.int32)
+    return decode_attention_pallas(
+        q, k, v, layer, ring_lengths(pos, k.shape[2]), head_dim=head_dim,
+        scale=scale, logit_cap=logit_cap, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "logit_cap"))

@@ -1,15 +1,8 @@
-"""Decode attention Pallas TPU kernels: ring-buffer cache and paged pool.
+"""Paged decode attention Pallas TPU kernel over the pool's pages
+(``paged_decode_attention_pallas``); the stacked ring cache's decode
+kernel is ``kernels/decode_attention.py``.
 
-**Ring kernel** (``decode_attention_pallas``): one query token per
-sequence attends over a contiguous ring cache with online softmax. Grid
-(batch·kv_heads, kv_blocks): the GQA query group for a kv head is one q
-block of shape (G, D), so the score matmul is (G×D)·(D×bk) on the MXU.
-Ring-slot validity (slot j holds token pos−((pos−j) mod C), valid iff ≥ 0)
-is computed in the jit wrapper — it depends on the traced ``pos`` — and
-streamed to the kernel as a mask, keeping the kernel scalar-free.
-
-**Paged kernel** (``paged_decode_attention_pallas``): the true
-HyperOffload §5.2 serving hot path. The request's KV lives as
+It is HyperOffload §5.2's serving hot path. The request's KV lives as
 *non-contiguous* pages in a device page buffer plus a partial tail page;
 instead of gathering + concatenating them per decode step (the
 ``offload.kvcache`` round trip this kernel replaces), the page table rides
@@ -35,95 +28,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.3819763e38
-
-
-def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
-                   m_scr, l_scr, acc_scr,
-                   *, scale: float, logit_cap: Optional[float],
-                   n_kv_blocks: int):
-    ik = pl.program_id(1)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, D)
-    k = k_ref[0, 0].astype(jnp.float32)               # (bk, D)
-    v = v_ref[0, 0].astype(jnp.float32)               # (bk, D)
-    valid = mask_ref[0]                               # (bk,) bool
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bk)
-    if logit_cap is not None:
-        s = logit_cap * jnp.tanh(s / logit_cap)
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(p, v)
-    m_scr[...] = m_new
-
-    @pl.when(ik == n_kv_blocks - 1)
-    def _finalize():
-        denom = jnp.where(l_scr[...] == 0.0, 1.0, l_scr[...])
-        o_ref[0, 0, ...] = (acc_scr[...] / denom).astype(o_ref.dtype)
-
-
-def decode_attention_pallas(
-    q: jax.Array,     # (B, Hq, D)
-    k: jax.Array,     # (B, Hkv, C, D)
-    v: jax.Array,
-    pos: jax.Array,   # scalar int32
-    *,
-    scale: float,
-    logit_cap: Optional[float] = None,
-    block_k: int = 128,
-    interpret: bool,
-) -> jax.Array:
-    b, hq, d = q.shape
-    hkv, c = k.shape[1], k.shape[2]
-    g = hq // hkv
-    block_k = min(block_k, max(8, c))
-    pad_k = (-c) % block_k
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-    ck = c + pad_k
-    nk = ck // block_k
-
-    # ring validity mask (see module docstring)
-    j = jnp.arange(ck)
-    tj = pos - jnp.mod(pos - j, c)
-    mask = ((tj >= 0) & (j < c))[None, :]             # (1, ck)
-
-    qg = q.reshape(b, hkv, g, d)
-    grid = (b * hkv, nk)
-
-    kernel = functools.partial(_decode_kernel, scale=scale,
-                               logit_cap=logit_cap, n_kv_blocks=nk)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bh, ik: (bh // hkv, bh % hkv, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bh, ik: (bh // hkv, bh % hkv, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bh, ik: (bh // hkv, bh % hkv, ik, 0)),
-            pl.BlockSpec((1, block_k), lambda bh, ik: (0, ik)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda bh, ik: (bh // hkv, bh % hkv, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qg, k, v, mask)
-    return out.reshape(b, hq, d)
 
 
 # ---------------------------------------------------------------------------

@@ -52,22 +52,23 @@ def decode_attention_ref(
     q: jax.Array,      # (B, Hq, D) — one token per sequence
     k: jax.Array,      # (B, Hkv, C, D) ring cache
     v: jax.Array,      # (B, Hkv, C, D)
-    pos: jax.Array,    # scalar int32 — token index just written
+    pos: jax.Array,    # scalar or (B,) int32 — token index just written
     *,
     scale: float,
     logit_cap: Optional[float] = None,
 ) -> jax.Array:
     """Attention of one query over a ring-buffer cache: slot j holds token
-    t_j = pos - ((pos - j) mod C); valid iff t_j >= 0."""
+    t_j = pos - ((pos - j) mod C); valid iff t_j >= 0. A scalar ``pos``
+    holds for every row."""
     b, hq, d = q.shape
     hkv, c = k.shape[1], k.shape[2]
     g = hq // hkv
     qf = q.astype(jnp.float32).reshape(b, hkv, g, d) * scale
     sc = jnp.einsum("bkgd,bkcd->bkgc", qf, k.astype(jnp.float32))
     sc = _softcap(sc, logit_cap)
-    j = jnp.arange(c)
-    tj = pos - jnp.mod(pos - j, c)
-    sc = jnp.where((tj >= 0)[None, None, None, :], sc, NEG_INF)
+    at = jnp.reshape(pos, (-1, 1, 1, 1))            # (1 or B, 1, 1, 1)
+    tj = at - jnp.mod(at - jnp.arange(c), c)
+    sc = jnp.where(tj >= 0, sc, NEG_INF)
     p = jax.nn.softmax(sc, axis=-1)
     out = jnp.einsum("bkgc,bkcd->bkgd", p, v.astype(jnp.float32))
     return out.reshape(b, hq, d).astype(q.dtype)

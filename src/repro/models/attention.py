@@ -11,17 +11,23 @@ minor would pad to 128, so the device would store a ``(…, Hkv, D)`` cache
 transposed and every decode step would relayout it). Decode reads and
 writes the cache in that layout and scores heads with a 0/1 head matrix
 instead of reshaping it; chunked prefill reshapes its one cached row into
-heads.
+heads. On a TPU the decode read is a Pallas kernel that fetches only the
+ring blocks holding each row's live tokens; elsewhere jnp scores every
+slot and masks the dead ones.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import LayerSpec, ModelConfig
+from repro.kernels.decode_attention import (
+    decode_attention_pallas, ring_lengths,
+)
 from repro.models import runtime
 from repro.models.common import (
     apply_rope, apply_rope_flat, dense_init, rmsnorm, softcap,
@@ -537,7 +543,10 @@ def attention_decode(
     ``cache["k"]``/``["v"]`` are the whole layer stack ``(L, B, C,
     Hkv·D)``: the token is written into layer ``layer`` in place and that
     layer is read where it lies, so the decode scan never copies a layer
-    out of the stack and back. MLA caches are one layer's."""
+    out of the stack and back. A row at position -1 is an empty batch
+    slot: its output means nothing, the kernel reads none of its cache,
+    and its write lands in a row that the next join overwrites whole. MLA
+    caches are one layer's."""
     if spec.mixer == "mla":
         return _mla_decode(cfg, p, x, pos, positions, cache)
     hd = cfg.head_dim
@@ -550,18 +559,37 @@ def attention_decode(
         k = apply_rope_flat(k, positions, cfg.rope_theta, hd, sections)
     new_k = _ring_write_token(cache["k"], k, pos, layer)
     new_v = _ring_write_token(cache["v"], v, pos, layer)
-    k_l = jax.lax.dynamic_index_in_dim(new_k, layer, 0, keepdims=False)
-    v_l = jax.lax.dynamic_index_in_dim(new_v, layer, 0, keepdims=False)
     scale = cfg.query_scale if cfg.query_scale is not None else hd ** -0.5
-    scores = _flat_scores(q[:, 0], k_l, hd) * scale       # (B,Hq,C)
-    scores = softcap(scores, cfg.attn_logit_softcap)
-    scores = _apply_valid_mask(scores, _ring_valid_mask(pos, k_l.shape[1]))
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = _flat_out(probs, v_l, hd).astype(x.dtype)[:, None]   # (B,1,Hq·D)
-    out = out @ p["wo"]
+    attend = dict(hd=hd, scale=scale, cap=cfg.attn_logit_softcap)
+    rows = jnp.broadcast_to(pos, (x.shape[0],)).astype(jnp.int32)
+    out = jax.lax.platform_dependent(
+        q[:, 0], new_k, new_v, layer, rows,
+        tpu=functools.partial(_attend_kernel, **attend, interpret=False),
+        default=functools.partial(_attend_xla, **attend))
+    out = out.astype(x.dtype)[:, None] @ p["wo"]          # (B,1,D)
     new_cache = dict(cache)
     new_cache["k"], new_cache["v"] = new_k, new_v
     return out, new_cache
+
+
+def _attend_kernel(q, k, v, layer, pos, *, hd, scale, cap, interpret):
+    """The decode read on a TPU: ``kernels/decode_attention.py``, which
+    fetches only each row's live blocks of the stack. (B, Hq·D) f32."""
+    return decode_attention_pallas(
+        q, k, v, layer, ring_lengths(pos, k.shape[2]), head_dim=hd,
+        scale=scale, logit_cap=cap, interpret=interpret)
+
+
+def _attend_xla(q, k, v, layer, pos, *, hd, scale, cap):
+    """The decode read elsewhere: layer ``layer`` of the stack, every ring
+    slot scored and the dead ones masked. (B, Hq·D) f32."""
+    k_l = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
+    v_l = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
+    scores = _flat_scores(q, k_l, hd) * scale              # (B,Hq,C)
+    scores = softcap(scores, cap)
+    scores = _apply_valid_mask(scores, _ring_valid_mask(pos, k_l.shape[1]))
+    probs = jax.nn.softmax(scores, axis=-1)
+    return _flat_out(probs, v_l, hd)
 
 
 def _head_matrix(hkv: int, hd: int, dtype) -> jax.Array:

@@ -62,6 +62,7 @@ import numpy as np
 
 from repro.core.costmodel import HardwareSpec, TPU_V5E
 from repro.core.insertion import InsertionOptions
+from repro.kernels.decode_attention import block_k, ring_lengths, slots_read
 from repro.models.model import Model
 from repro.obs.metrics import SECONDS_BUCKETS, MetricsRegistry
 from repro.obs.trace import NULL_TRACER
@@ -132,6 +133,11 @@ class SchedStats:
     preemptions: int = 0          # running sequences parked for a deadline
     resumes: int = 0              # preempted sequences restored to a slot
     shed: int = 0                 # requests dropped as deadline-infeasible
+    # ring slots the decode read fetches (each row's live kv blocks) and
+    # the slots of every row's whole ring, summed over self-attention
+    # layers and decode steps (``kernels/decode_attention.py``)
+    decode_kv_slots_read: int = 0
+    decode_kv_slots_ring: int = 0
 
 
 class ContinuousScheduler:
@@ -185,6 +191,13 @@ class ContinuousScheduler:
         self._decode = jit_decode(model)
         self.cache = model.init_cache(cfg.max_batch, cfg.max_seq,
                                       cfg.cache_dtype)
+        # (layers, ring slots, kv block) of each self-attention K stack
+        # (L, B, C, Hkv·D): what the decode read's grid walks
+        self._kv_rings = [
+            (k.shape[0], k.shape[2],
+             block_k(k.shape[2], k.shape[3], k.dtype.itemsize))
+            for seg in self.cache["segments"] for st in seg.values()
+            for name, k in st.items() if name == "k"]
         self.slots: List[Optional[RequestState]] = [None] * cfg.max_batch
         # flat layer index -> (segment, repeat, pattern position); matches
         # cfg.layer_specs() and the decode-graph layer numbering
@@ -846,10 +859,14 @@ class ContinuousScheduler:
             return []
         b = self.cfg.max_batch
         tok = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b,), np.int32)
+        pos = np.full((b,), -1, np.int32)     # -1: an empty slot
         for s in live:
             tok[s.slot, 0] = s.last_tok
             pos[s.slot] = s.pos
+        for layers, c, bk in self._kv_rings:
+            self.stats.decode_kv_slots_read += layers * slots_read(
+                ring_lengths(pos, c), bk)
+            self.stats.decode_kv_slots_ring += layers * b * c
         tr = self._tracer
         with tr.span("sched", "dispatch"):
             logits, self.cache = self._decode(

@@ -1,17 +1,18 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype/flag sweeps in
 interpret mode (CPU)."""
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops
+from repro.kernels import decode_attention as rd
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.paged_attention import (
-    decode_attention_pallas,
-    paged_decode_attention_pallas,
-)
+from repro.kernels.paged_attention import paged_decode_attention_pallas
 from repro.kernels.ref import (
     decode_attention_ref,
     flash_attention_ref,
@@ -60,24 +61,6 @@ def test_flash_attention_flags(window, cap, causal):
                                  block_q=16, block_k=16, interpret=True)
     ref = flash_attention_ref(q, k, v, scale=0.2, causal=causal,
                               window=window, logit_cap=cap)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-@pytest.mark.parametrize("b,hq,hkv,c,d,pos", [
-    (2, 4, 2, 64, 32, 5),
-    (2, 4, 2, 64, 32, 63),
-    (2, 4, 2, 64, 32, 200),   # wrapped ring
-    (1, 8, 8, 100, 16, 99),
-    (3, 6, 1, 48, 64, 20),
-])
-def test_decode_attention(b, hq, hkv, c, d, pos):
-    ks = jax.random.split(jax.random.key(2), 3)
-    q = jax.random.normal(ks[0], (b, hq, d))
-    k = jax.random.normal(ks[1], (b, hkv, c, d))
-    v = jax.random.normal(ks[2], (b, hkv, c, d))
-    out = decode_attention_pallas(q, k, v, jnp.int32(pos), scale=d ** -0.5,
-                                  block_k=32, interpret=True)
-    ref = decode_attention_ref(q, k, v, jnp.int32(pos), scale=d ** -0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -145,20 +128,196 @@ def test_paged_decode_ref_is_bitwise_the_gather_path():
         assert bool(jnp.all(ref == gather))
 
 
-@pytest.mark.parametrize("pos", [63, 64, 65, 95, 96, 200])
+# ---------------------------------------------------------------------------
+# ring decode: the stacked-cache kernel vs the jnp decode read
+# ---------------------------------------------------------------------------
+
+#: (Hq, Hkv, D, C): phi3-mini's 32 heads of 96 (MHA; a head does not tile
+#: the 128 lanes) and Granite 3.0's 24 query over 8 kv heads of 64 (GQA),
+#: over rings of several kv blocks
+DECODE_SHAPES = {"phi3": (32, 32, 96, 768), "granite": (24, 8, 64, 2048)}
+#: the layer read, of a two-layer stack
+LAYER = 1
+
+
+def _stack(shape, b, dtype, seed):
+    """(q (B, Hq·D), k, v (2, B, C, Hkv·D), head_dim, bk) at ``shape``."""
+    hq, hkv, hd, c = DECODE_SHAPES[shape]
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (b, hq * hd)).astype(dtype)
+    k = jax.random.normal(ks[1], (2, b, c, hkv * hd)).astype(dtype)
+    v = jax.random.normal(ks[2], (2, b, c, hkv * hd)).astype(dtype)
+    return q, k, v, hd, rd.block_k(c, hkv * hd, jnp.dtype(dtype).itemsize)
+
+
+def _kernel_and_jnp(q, k, v, pos, hd, cap, scale=0.1):
+    """The kernel (interpreted) and the jnp decode read of
+    ``attention_decode`` on the same stack and positions, as arrays."""
+    from repro.models import attention as A
+    kw = dict(hd=hd, scale=scale, cap=cap)
+    pos = jnp.asarray(pos, jnp.int32)
+    got = A._attend_kernel(q, k, v, jnp.int32(LAYER), pos, **kw,
+                           interpret=True)
+    want = A._attend_xla(q, k, v, jnp.int32(LAYER), pos, **kw)
+    return np.asarray(got), np.asarray(want)
+
+
+def _length(name, bk, c):
+    return {"0": 0, "1": 1, "bk-1": bk - 1, "bk": bk, "bk+1": bk + 1,
+            "C-1": c - 1, "C": c}[name]
+
+
+@pytest.mark.parametrize("length", ["0", "1", "bk-1", "bk", "bk+1", "C-1",
+                                    "C"])
+@pytest.mark.parametrize("cap", [None, 30.0])
+@pytest.mark.parametrize("shape", DECODE_SHAPES)
+def test_decode_attention(shape, cap, length):
+    """Rows of the length under test around an empty row and a longer
+    one: a live row equals the jnp read, which scores every slot and
+    masks the dead ones, and an empty row reads as zeros. In float32 one
+    key gained or lost moves a row by far more than the tolerance."""
+    q, k, v, hd, bk = _stack(shape, 4, jnp.float32, seed=20)
+    c = k.shape[2]
+    n = _length(length, bk, c)
+    lens = np.array([n, 0, c // 2 + 3, n])
+    got, want = _kernel_and_jnp(q, k, v, lens - 1, hd, cap)
+    live = lens > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("shape", DECODE_SHAPES)
+def test_decode_attention_bf16(shape):
+    """bfloat16 operands, float32 sums, as served: within bfloat16
+    rounding of the jnp read, which weighs values by normalised
+    probabilities where the kernel weighs them before dividing."""
+    q, k, v, hd, bk = _stack(shape, 4, jnp.bfloat16, seed=21)
+    c = k.shape[2]
+    pos = np.array([c // 3, bk, -1, c + 7])
+    got, want = _kernel_and_jnp(q, k, v, pos, hd, None, hd ** -0.5)
+    np.testing.assert_allclose(got[pos >= 0], want[pos >= 0], atol=4e-3)
+
+
+@pytest.mark.parametrize("pos", ["C-1", "C", "C+1", "C+bk-1", "2C+5",
+                                 "3C-1"])
 def test_decode_attention_ring_wrap_mid_block(pos):
-    """Ring wrap regression (ISSUE satellite): positions at, just past,
-    and mid-way through block boundaries of the ring cache, where the
-    validity mask wraps inside a kv block."""
-    b, hq, hkv, c, d = 2, 4, 2, 64, 32
-    ks = jax.random.split(jax.random.key(13), 3)
-    q = jax.random.normal(ks[0], (b, hq, d))
-    k = jax.random.normal(ks[1], (b, hkv, c, d))
-    v = jax.random.normal(ks[2], (b, hkv, c, d))
-    out = decode_attention_pallas(q, k, v, jnp.int32(pos), scale=d ** -0.5,
-                                  block_k=32, interpret=True)
-    ref = decode_attention_ref(q, k, v, jnp.int32(pos), scale=d ** -0.5)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    """A ring past its wrap (``pos >= C``): slot j holds token
+    ``pos - ((pos - j) mod C)`` and every slot is live, so the kernel reads
+    every block. One scalar position for the batch, as
+    ``ServeEngine.generate`` decodes, broadcast to the rows."""
+    q, k, v, hd, bk = _stack("phi3", 2, jnp.float32, seed=22)
+    c = k.shape[2]
+    p = {"C-1": c - 1, "C": c, "C+1": c + 1, "C+bk-1": c + bk - 1,
+         "2C+5": 2 * c + 5, "3C-1": 3 * c - 1}[pos]
+    got, want = _kernel_and_jnp(q, k, v, jnp.broadcast_to(p, (2,)), hd, 50.0)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_decode_attention_ops_wrapper_jits():
+    """``ops.decode_attention`` (scalar or per-row positions) against
+    ``kernels.ref``'s ring oracle on the layer, in heads layout."""
+    q, k, v, hd, bk = _stack("granite", 3, jnp.float32, seed=23)
+    b, c, x = k.shape[1:]
+    heads = lambda a: a[LAYER].reshape(b, c, x // hd, hd).transpose(0, 2, 1, 3)
+    for pos in (jnp.int32(bk + 2), jnp.asarray([5, -1, c + 3], jnp.int32)):
+        out = ops.decode_attention(q, k, v, jnp.int32(LAYER), pos,
+                                   head_dim=hd, scale=hd ** -0.5)
+        want = decode_attention_ref(q.reshape(b, -1, hd), heads(k), heads(v),
+                                    pos, scale=hd ** -0.5)
+        live = np.broadcast_to(np.asarray(pos) >= 0, (b,))
+        np.testing.assert_allclose(np.asarray(out)[live],
+                                   np.asarray(want).reshape(b, -1)[live],
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("lens", [
+    (300, 0, 0, 17, 768, 1, 0, 255),
+    (0, 0, 256, 0, 512, 513, 0, 0),
+    (768, 768, 768, 768),
+    (1, 0, 1, 0),
+    (0, 0, 0),
+])
+def test_decode_kv_index_map_fetches_only_live_blocks(lens):
+    """Walk the grid through the kernel's own index map: the pipeline
+    fetches a K/V block whenever the index differs from the step
+    before's. No fetched block lies past its row's last live block, an
+    empty row fetches none, and ``slots_read`` (the scheduler's
+    ``decode_kv_slots_read``) counts exactly the fetched slots."""
+    bk, c = 256, 768
+    lens = np.asarray(lens, np.int32)
+    sched = rd.kv_schedule(jnp.asarray(lens), bk)
+    refs = (np.zeros(1, np.int32), lens) + tuple(np.asarray(a) for a in sched)
+    fetched, prev = [], None
+    for b in range(len(lens)):
+        for ik in range(c // bk):
+            idx = tuple(int(i) for i in rd.kv_block_index(b, ik, *refs))
+            if idx != prev:
+                fetched.append(idx)
+            prev = idx
+    want = [(b, blk) for b in range(len(lens))
+            for blk in range(-(-int(lens[b]) // bk))]
+    got = [(row, blk) for _, row, blk, _ in fetched]
+    # the first grid step fetches a block whatever the lengths
+    assert got == (want if lens.any() else [(0, 0)])
+    assert rd.slots_read(lens, bk) == len(fetched) * bk
+
+
+@pytest.mark.parametrize("c,x,itemsize,want", [
+    (768, 3072, 2, 256),     # phi3 at the chat cell's ring
+    (768, 512, 2, 768),      # Granite: the whole ring fits
+    (2048, 512, 2, 1024),
+    (768, 3072, 4, 128),
+    (100, 128, 4, 100),      # no sublane-tiled divisor: one block
+])
+def test_decode_block_size_follows_width_and_vmem(c, x, itemsize, want):
+    bk = rd.block_k(c, x, itemsize)
+    assert bk == want and c % bk == 0
+    assert bk == c or 4 * bk * x * itemsize <= rd.KV_VMEM_BYTES
+
+
+def _kernel_read(monkeypatch):
+    """Make every decode read the interpreted kernel, as on a TPU."""
+    from repro.models import attention as A
+    monkeypatch.setattr(A, "_attend_xla",
+                        functools.partial(A._attend_kernel, interpret=True))
+
+
+@pytest.mark.parametrize("arch,kv_heads", [("gemma2-9b", 2),
+                                           ("phi3-mini-3.8b", None)])
+@pytest.mark.parametrize("per_row", [False, True])
+def test_decode_step_kernel_read_matches_jnp(monkeypatch, arch, kv_heads,
+                                             per_row):
+    """Whole decode steps of a tiny model through the kernel against the
+    jnp read: gemma2's softcap, query scale and sliding-window rings that
+    wrap (an 8-slot window beside full rings), with GQA; scalar positions
+    as ``ServeEngine`` decodes, or per-row ones with an empty slot."""
+    from repro.configs import REGISTRY
+    from repro.models.model import Model
+    cfg = REGISTRY[arch].reduced()
+    if kv_heads:
+        cfg = dataclasses.replace(cfg, n_kv_heads=kv_heads)
+    model = Model(cfg=cfg, swa_override=8)
+    params = model.init(jax.random.key(0))
+    b, max_seq = 3, 24
+    cache = jax.tree.map(
+        lambda a: jax.random.normal(jax.random.key(1), a.shape, a.dtype),
+        model.init_cache(b, max_seq))
+    tok = jax.random.randint(jax.random.key(2), (b, 1), 0, cfg.vocab_size)
+    start = np.array([5, -1, 13]) if per_row else np.full(b, 5)
+
+    def run():
+        step = jax.jit(lambda *a: model.decode_step(*a))
+        c, out = cache, []
+        for i in range(6):
+            pos = np.where(start >= 0, start + 2 * i, -1)
+            pos = jnp.asarray(pos if per_row else pos[0], jnp.int32)
+            logits, c = step(params, c, tok, pos)
+            out.append(np.asarray(logits[start >= 0]))
+        return np.stack(out)
+
+    want = run()
+    _kernel_read(monkeypatch)
+    np.testing.assert_allclose(run(), want, atol=1e-4, rtol=1e-4)
 
 
 def test_paged_decode_ops_wrapper_jits():

@@ -3,6 +3,9 @@
 control never over-committing pool capacity, and plan-driven prefetch
 issuing ahead of consumption."""
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -68,6 +71,47 @@ def test_continuous_matches_sequential_greedy(model_and_params):
         np.testing.assert_array_equal(out[r.req_id], ref[r.req_id])
     sched.close()
     sched.close()   # idempotent
+
+
+def test_decode_through_ring_kernel_counts_live_blocks(model_and_params,
+                                                      monkeypatch):
+    """Empty slots decode at position -1. With the decode read through the
+    ring decode kernel (interpreted, as it runs on a TPU) the greedy tokens
+    equal the sequential jnp reference, and the scheduler's counters
+    advance by what the kernel's grid fetches at each step's positions
+    (``kernels.decode_attention.slots_read``) and by the whole rings."""
+    from repro.kernels import decode_attention as rd
+    from repro.models import attention as A
+    model, params = model_and_params
+    reqs = _mixed_trace()
+    ref = _sequential_reference(model, params, reqs)
+    monkeypatch.setattr(A, "_attend_xla",
+                        functools.partial(A._attend_kernel, interpret=True))
+    # a model of its own, so that no decode traced on the jnp read is reused
+    kernel_model = build_model(dataclasses.replace(
+        CFG, name=CFG.name + "-ring-kernel"))
+    sched = ContinuousScheduler(kernel_model, params,
+                                SchedulerConfig(max_batch=2, max_seq=MAX_SEQ))
+    positions = []
+    decode = sched._decode
+
+    def spy(params, cache, tok, pos):
+        positions.append(np.asarray(pos))
+        return decode(params, cache, tok, pos)
+
+    sched._decode = spy
+    out = sched.run(reqs)
+    for r in reqs:
+        np.testing.assert_array_equal(out[r.req_id], ref[r.req_id])
+    assert any((p == -1).any() for p in positions)
+    bk = rd.block_k(MAX_SEQ, CFG.n_kv_heads * CFG.head_dim, 4)
+    want = CFG.n_layers * sum(
+        rd.slots_read(rd.ring_lengths(p, MAX_SEQ), bk) for p in positions)
+    assert sched.stats.decode_kv_slots_read == want
+    assert sched.stats.decode_kv_slots_ring == (
+        CFG.n_layers * len(positions) * 2 * MAX_SEQ)
+    assert 0 < want < sched.stats.decode_kv_slots_ring
+    sched.close()
 
 
 def test_offload_matches_sequential_and_evicts_to_host(model_and_params):

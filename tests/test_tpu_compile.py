@@ -23,11 +23,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import REGISTRY
+from repro.kernels.decode_attention import KERNEL_NAME, decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.paged_attention import (
-    decode_attention_pallas,
-    paged_decode_attention_pallas,
-)
+from repro.kernels.paged_attention import paged_decode_attention_pallas
 from repro.models.model import build_model
 
 V5E_HBM_BYTES = 16 * 10**9
@@ -64,18 +62,21 @@ def _spec(shape, sharding, dtype=jnp.bfloat16):
 
 def _kernel_case(name, chip):
     """(kernel, argument specs) at phi3 widths: prefill over 512 tokens,
-    ring decode over a full cache, paged decode over 12 of 16 pages."""
+    ring decode over the chat cell's stacked cache (and at Granite 3.0's
+    24 query over 8 kv heads of 64), paged decode over 12 of 16 pages."""
     scale = D ** -0.5
     if name == "flash":
         qkv = [_spec((1, H, 512, D), chip)] * 3
         return (lambda q, k, v: flash_attention_pallas(
             q, k, v, scale=scale, interpret=False)), qkv
-    if name == "decode":
-        kv = _spec((BATCH, H, MAX_SEQ, D), chip)
-        return (lambda q, k, v, pos: decode_attention_pallas(
-            q, k, v, pos, scale=scale, interpret=False)), [
-            _spec((BATCH, H, D), chip), kv, kv,
-            _spec((), chip, jnp.int32)]
+    if name in ("decode", "decode_gqa"):
+        hq, hkv, hd = (H, H, D) if name == "decode" else (24, 8, 64)
+        kv = _spec((32, CHAT_BATCH, CHAT_SEQ, hkv * hd), chip)
+        return (lambda q, k, v, r, n: decode_attention_pallas(
+            q, k, v, r, n, head_dim=hd, scale=hd ** -0.5,
+            interpret=False)), [
+            _spec((CHAT_BATCH, hq * hd), chip), kv, kv,
+            _spec((), chip, jnp.int32), _spec((CHAT_BATCH,), chip, jnp.int32)]
     pages = _spec((16, BATCH, H, PAGE, D), chip)
     tail = _spec((BATCH, H, PAGE, D), chip)
     return (lambda q, kp, vp, t, kt, vt, n: paged_decode_attention_pallas(
@@ -85,7 +86,8 @@ def _kernel_case(name, chip):
         _spec((), chip, jnp.int32)]
 
 
-@pytest.mark.parametrize("name", ["flash", "decode", "paged"])
+@pytest.mark.parametrize("name", ["flash", "decode", "decode_gqa",
+                                  "paged"])
 def test_attention_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_case(name, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
@@ -160,7 +162,9 @@ def test_decode_layer_loop_reads_cache_and_weights_in_place(one_chip):
     layer scan's loop body nothing as large as a projection weight (3072²
     bf16, 18.9 MB) or a layer's K or V (37.7 MB) is sliced out or relaid
     out, and the entry copies no cache-sized buffer: each step reads the
-    cache and the weights once, in their stored layout."""
+    cache and the weights once, in their stored layout. The layer's
+    attention read is one call of the ring decode kernel, which takes the
+    whole stack, and nothing else reads the cache's slots."""
     model = build_model(REGISTRY["phi3-mini-3.8b"])
     on_chip = lambda s: _spec(s.shape, one_chip, s.dtype)
     params = jax.tree.map(on_chip, model.param_specs(jnp.bfloat16))
@@ -177,6 +181,11 @@ def test_decode_layer_loop_reads_cache_and_weights_in_place(one_chip):
     moved = [(op, name, n) for op, name, n in _ops(comps, bodies[0])
              if op in ("copy", "transpose", "dynamic-slice") and n >= weight]
     assert not moved, moved
+    calls = [line for line in comps[bodies[0]] if " custom-call(" in line]
+    assert len(calls) == 1 and f"%{KERNEL_NAME}" in calls[0], calls
+    # the jnp read's two whole-ring einsums are gone
+    assert not [line for line in hlo.splitlines()
+                if "bcx,bxh->bhc" in line or "bhc,bcx->bhx" in line]
     leaf = CHAT_BATCH * CHAT_SEQ * 3072 * 2 * 32
     entry_copies = [(op, name, n) for op, name, n in _ops(comps, "")
                     if op in ("copy", "transpose") and n >= leaf]
