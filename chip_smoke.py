@@ -9,7 +9,7 @@ on any failure:
 1. **serve** — phi3-mini-3.8b at full width and depth (d_model 3072,
    32 layers, 32 heads of 96, d_ff 8192, vocab 32064), random bfloat16
    weights from ``SEED`` and a bfloat16 KV cache, built and served by
-   ``repro.launch.serve``'s helpers, as ``launch/serve.py`` does. One
+   ``repro.launch.serve``'s helpers, as its ``main`` does. One
    Poisson trace (6
    requests, prompts 128-512 tokens, 32 greedy new tokens each, batch 4,
    ``max_seq`` 768, chunked prefill) is served first in ``resident``

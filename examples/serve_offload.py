@@ -65,7 +65,7 @@ def main(trace_out=None):
     # host tier sized to exactly hold every K and V page (overflow would
     # spill to the remote tier) — tier topology is config, not a call site
     session = HyperOffloadSession(OffloadConfig(
-        mode="paged", max_seq=ctx + 64, page_size=page,
+        max_seq=ctx + 64, page_size=page,
         host_capacity=2 * n_pages * page_nbytes,
         telemetry=_telemetry(trace_out)))
     cache = session.paged_kv(batch=b, n_kv_heads=hkv, head_dim=d)

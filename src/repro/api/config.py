@@ -5,10 +5,9 @@ transfer depths were scattered across five constructors with ad-hoc kwargs
 and magic numbers. `OffloadConfig` owns all of it in a single frozen,
 serializable object:
 
-- **mode** — what the session is serving: ``resident`` (KV stays on
-  device), ``kv_offload`` (whole-cache / per-page pool round trips),
-  ``paged`` (page-granular `PagedKVCache` with sparse selection),
-  ``continuous`` (continuous-batching scheduler, resident pages);
+- **mode** — where the served KV state lives between steps:
+  ``resident`` (on the device) or ``kv_offload`` (parked in the pool and
+  prefetched back, plan-driven);
 - **tier topology** — either the legacy per-tier byte capacities of the
   default device/host/remote chain (``None`` = unbounded), or a full
   declarative ``TierTopology`` (ordered ``TierSpec`` chain with backend
@@ -20,8 +19,8 @@ serializable object:
 - **hardware** — a `HardwareSpec` by registry name (serializable) or
   instance, driving the planner's cost model;
 - **planner knobs** — `InsertionOptions` / `ScheduleOptions`; ``None``
-  insertion means the mode-appropriate default (`PAGED_INSERTION` for the
-  offload modes — the old hard-coded ``min_bytes=1``);
+  insertion means the mode-appropriate default (`PAGED_INSERTION` under
+  ``kv_offload`` — the old hard-coded ``min_bytes=1``);
 - **transfer depth policy** — ``"auto"`` derives depth from the consumer's
   shape via `pool.auto_depth` (f(pages, layers)); an int pins it;
 - **training memory policy** — remat mode and the optimizer-state offload
@@ -47,7 +46,7 @@ from repro.pool.topology import TierTopology
 from repro.pool.transfer import auto_depth
 from repro.slo.policy import SLOConfig
 
-MODES = ("resident", "kv_offload", "paged", "continuous")
+MODES = ("resident", "kv_offload")
 REMAT_MODES = ("none", "full", "offload")
 
 #: Hardware specs addressable by name in a serialized config.
@@ -56,16 +55,11 @@ HW_SPECS: Dict[str, HardwareSpec] = {
     ASCEND_LIKE.name: ASCEND_LIKE,
 }
 
-#: modes whose KV tensors live in the pool (mandatory prefetches)
-_OFFLOAD_MODES = ("kv_offload", "paged", "continuous")
-
-
 @dataclass(frozen=True)
 class PrefixCacheConfig:
     """Cross-request prefix cache knobs (``repro.prefix``): disabled by
-    default; enabling requires chunked prefill (``chunk_size``) on a
-    scheduler mode, since a prefix hit resumes prefill at the match
-    offset."""
+    default; enabling requires chunked prefill (``chunk_size``), since a
+    prefix hit resumes prefill at the match offset."""
 
     enable: bool = False
     page_size: int = 16            # tokens per cached/shared KV page
@@ -209,7 +203,7 @@ class OffloadConfig:
     prefill_tokens: Optional[int] = None
     page_size: int = 32
     cache_dtype: str = "float32"
-    # cross-request prefix cache (scheduler modes with chunked prefill)
+    # cross-request prefix cache (requires chunked prefill)
     prefix_cache: PrefixCacheConfig = field(default_factory=PrefixCacheConfig)
     # quantized KV page codec below a tier boundary (repro.pool.codec)
     kv_codec: KVCodecConfig = field(default_factory=KVCodecConfig)
@@ -262,15 +256,6 @@ class OffloadConfig:
                     "prefix_cache.enable requires chunk_size (a prefix hit "
                     "resumes prefill at the match offset, which only the "
                     "chunked path supports)")
-            if self.mode not in ("continuous", "kv_offload"):
-                raise ValueError(
-                    "prefix_cache.enable requires a scheduler mode "
-                    "('continuous' or 'kv_offload'), "
-                    f"got mode={self.mode!r}")
-        if self.slo.enable and self.mode not in ("continuous", "kv_offload"):
-            raise ValueError(
-                "slo.enable requires a scheduler mode ('continuous' or "
-                f"'kv_offload'), got mode={self.mode!r}")
         if self.topology is not None:
             if not isinstance(self.topology, TierTopology):
                 raise ValueError(
@@ -332,13 +317,12 @@ class OffloadConfig:
         return jnp.dtype(self.cache_dtype)
 
     def insertion_options(self) -> InsertionOptions:
-        """Explicit options, else the mode default: offload modes plan every
-        pool-resident KV tensor (`PAGED_INSERTION`, the documented old
-        ``min_bytes=1``); resident keeps the cost-model thresholds."""
+        """Explicit options, else the mode default: ``kv_offload`` plans
+        every pool-resident KV tensor (`PAGED_INSERTION`, the documented
+        old ``min_bytes=1``); resident keeps the cost-model thresholds."""
         if self.insertion is not None:
             return self.insertion
-        return PAGED_INSERTION if self.mode in _OFFLOAD_MODES \
-            else InsertionOptions()
+        return PAGED_INSERTION if self.offload_kv else InsertionOptions()
 
     def depth_for(self, *, layers: Optional[int] = None,
                   pages: Optional[int] = None) -> int:

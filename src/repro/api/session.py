@@ -324,7 +324,7 @@ class HyperOffloadSession:
             base.update(overrides)
             if (base["kv_offload"] and c.insertion is None
                     and "insert_opts" not in overrides):
-                # a kv_offload override on a non-offload-mode session must
+                # a kv_offload override on a resident session must
                 # still plan the mandatory prefetch of every pool-resident
                 # KV tensor — the resident-mode cost-model thresholds would
                 # silently filter small KV leaves out of the plan

@@ -1,32 +1,49 @@
-"""Serving launcher: batched generation through the `repro.api` front door.
+"""Serving launcher: one continuous-batching serve over a Poisson trace,
+with the telemetry front door exposed as flags.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma2-9b --smoke \
-        --prompt-len 32 --new-tokens 32 --batch 4 [--mode kv_offload]
+    PYTHONPATH=src python -m repro.launch.serve [--arch ARCH] [--smoke]
+        [--mode resident|kv_offload]
+        [--trace-out TRACE.json] [--stats-json STATS.json]
 
-Without ``--smoke`` the arch runs at its published widths and depth, with
-random bfloat16 weights and a bfloat16 KV cache; ``--smoke`` runs its
-reduced float32 config. ``--mode`` selects the `OffloadConfig` mode.
-``--remote-bw GB/s`` swaps the default topology's remote tier for a
-bandwidth-throttled modeled tier (the paper's Fig. 6 D2H sweep, one point
-per invocation), and ``--recalibrate`` re-runs the generation after
-feeding the measured per-tier-pair bandwidths back into planning.
+The model is ``--arch`` at its published widths and depth, with random
+bfloat16 weights and a bfloat16 KV cache (a 3.8B model's float32 weights
+alone fill a 16 GB chip). ``--smoke`` serves the arch's reduced float32
+config instead, small enough for the CPU.
+
+``--trace-out`` enables the session's telemetry block
+(``OffloadConfig.telemetry``) and writes the Chrome trace-event /
+Perfetto JSON file there on session close — open it at
+https://ui.perfetto.dev. ``--stats-json`` writes the merged
+``session.stats()`` snapshot (pool/transfer/sched counters, plus the
+latency histograms and trace-ring state when tracing is on). With
+neither flag telemetry stays disabled and no tracer is ever constructed.
+
+SLO flags: ``--interactive-fraction F`` annotates the trace with mixed
+interactive/batch priority classes and TTFT deadlines; ``--slo`` turns
+on the SLO-aware scheduler (deadline-first admission, preemption, early
+shedding — ``OffloadConfig.slo``); ``--overload X`` multiplies the
+arrival rate by X to push the trace past capacity. With any of them the
+launcher prints a per-class SLO attainment summary after the run.
+
+``load_model`` and ``offload_config`` are the serving helpers the
+benchmark (``bench/program.py``) and ``chip_smoke.py`` build on.
 """
 
 from __future__ import annotations
 
 import argparse
+import json
 import time
 
 import jax
 import jax.numpy as jnp
 
 from repro.api import HyperOffloadSession, OffloadConfig
+from repro.api.config import MODES, TelemetryConfig
 from repro.configs import REGISTRY
-from repro.data.pipeline import SyntheticTokens
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model, build_model
 from repro.offload.kvcache import worst_case_page_bytes
-from repro.pool import TierTopology, sweep_topologies
 
 
 def load_model(arch: str, *, smoke: bool, seed: int = 0):
@@ -59,68 +76,100 @@ def offload_config(model: Model, *, mode: str, max_batch: int,
 
 
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=sorted(REGISTRY), required=True)
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--new-tokens", type=int, default=32)
-    ap.add_argument("--batch", type=int, default=4)
-    # this launcher drives ServeEngine only — the paged/continuous modes
-    # live in examples/serve_offload.py and benchmarks/serve_continuous.py
-    ap.add_argument("--mode", choices=("resident", "kv_offload"),
-                    default="resident")
-    ap.add_argument("--temperature", type=float, default=0.0)
+    # only the serve loop needs these: importing the helpers above stays
+    # as light as it is for the benchmark
+    from repro.sched import poisson_trace
+    from repro.slo import SLOConfig, attainment_summary
+
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--arch", choices=sorted(REGISTRY),
+                    default="phi3-mini-3.8b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's reduced float32 config")
+    ap.add_argument("--mode", choices=MODES, default="kv_offload")
+    ap.add_argument("--requests", type=int, default=8)
+    ap.add_argument("--rate", type=float, default=0.8,
+                    help="Poisson arrivals per scheduler step")
+    ap.add_argument("--max-batch", type=int, default=3)
+    ap.add_argument("--max-seq", type=int, default=48)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--remote-bw", type=float, default=None, metavar="GBPS",
-                    help="throttle the remote tier's read bandwidth to this "
-                         "many GB/s (modeled tier; Fig.-6-style sweep point)")
-    ap.add_argument("--recalibrate", action="store_true",
-                    help="after the run, replan from measured per-tier-pair "
-                         "bandwidths and generate once more")
+    ap.add_argument("--trace-out", default=None, metavar="PATH",
+                    help="enable telemetry and write the Chrome trace here")
+    ap.add_argument("--stats-json", default=None, metavar="PATH",
+                    help="write the merged session.stats() snapshot here")
+    ap.add_argument("--slo", action="store_true",
+                    help="SLO-aware scheduling: deadline-first admission, "
+                         "preemption, early shedding")
+    ap.add_argument("--overload", type=float, default=None, metavar="X",
+                    help="multiply --rate by X (drive the trace past "
+                         "capacity)")
+    ap.add_argument("--interactive-fraction", type=float, default=None,
+                    metavar="F",
+                    help="annotate the trace: F of requests interactive "
+                         "(TTFT deadline), rest batch")
     args = ap.parse_args(argv)
-    mode = args.mode
+    if args.slo and args.interactive_fraction is None:
+        args.interactive_fraction = 0.35   # --slo alone still demos SLOs
 
     enable_compile_cache()
     model, params, dtype = load_model(args.arch, smoke=args.smoke,
                                       seed=args.seed)
     cfg = model.cfg
-    data = SyntheticTokens(cfg.vocab_size, seq_len=args.prompt_len,
-                           global_batch=args.batch, seed=args.seed)
-    batch = data.batch(0, cfg)
-    batch.pop("targets", None)
 
-    topology = None
-    if args.remote_bw is not None:
-        topology, = sweep_topologies(
-            TierTopology.default(), "remote",
-            read_bws=[args.remote_bw * 1e9])
-    config = OffloadConfig(mode=mode, max_batch=args.batch,
-                           max_seq=args.prompt_len + args.new_tokens,
-                           cache_dtype=dtype, topology=topology)
-    with HyperOffloadSession(config) as session:
-        engine = session.serve_engine(model, params)
-        t0 = time.time()
-        out = engine.generate(batch, args.new_tokens,
-                              temperature=args.temperature, seed=args.seed)
-        dt = time.time() - t0
-        toks = args.batch * args.new_tokens
-        print(f"arch={cfg.name} mode={mode} "
-              f"tiers={'/'.join(session.pool.spill_order)} "
-              f"generated {out.shape} in {dt:.2f}s ({toks/dt:.1f} tok/s)")
-        print("first sequence:", out[0].tolist())
-        s = session.stats()
-        print(f"stats: {s['serve']} pool_puts={s['pool']['puts']} "
-              f"pool_gets={s['pool']['gets']}")
-        if args.recalibrate:
-            spec = session.recalibrate()
-            t0 = time.time()
-            out = engine.generate(batch, args.new_tokens,
-                                  temperature=args.temperature,
-                                  seed=args.seed)
-            dt2 = time.time() - t0
-            print(f"recalibrated hw={spec.name} "
-                  f"d2r={spec.pool_bw_d2r:.3g}B/s r2d={spec.pool_bw_r2d:.3g}B/s "
-                  f"rerun {dt2:.2f}s ({toks/dt2:.1f} tok/s)")
+    extra = dict(prefill_budget=2)
+    if args.trace_out is not None:
+        extra["telemetry"] = TelemetryConfig(enable=True,
+                                             trace_path=args.trace_out)
+    if args.slo:
+        extra["slo"] = SLOConfig(enable=True)
+
+    session = HyperOffloadSession(offload_config(
+        model, mode=args.mode, max_batch=args.max_batch,
+        max_seq=args.max_seq, cache_dtype=dtype, **extra))
+    sched = session.scheduler(model, params)
+    rate = args.rate * (args.overload or 1.0)
+    trace = poisson_trace(args.requests, rate=rate,
+                          vocab_size=cfg.vocab_size, prompt_lens=(4, 16),
+                          new_tokens=(2, 12), prompt_quantum=4,
+                          interactive_fraction=args.interactive_fraction,
+                          seed=args.seed)
+    t0 = time.time()
+    out = sched.run(trace)
+    wall = time.time() - t0
+    tokens = sum(len(v) for v in out.values())
+    print(f"serve,{args.mode},requests:{len(out)},tokens:{tokens},"
+          f"steps:{sched.stats.steps},wall_s:{wall:.2f}")
+
+    if args.slo or args.interactive_fraction is not None:
+        att = attainment_summary(sched.finished.values())
+        st = sched.stats
+        print(f"serve,slo,goodput_tokens:{att['met_tokens']},"
+              f"goodput_tok/step:"
+              f"{att['met_tokens'] / max(sched.now, 1e-9):.2f},"
+              f"preemptions:{st.preemptions},resumes:{st.resumes},"
+              f"shed:{st.shed}")
+        for cls, c in sorted(att["classes"].items()):
+            tta = c["ttft_attainment"]
+            print(f"serve,slo_class,{cls},requests:{c['requests']},"
+                  f"met_tokens:{c['met_tokens']}/{c['tokens']},"
+                  f"shed:{c['shed']},ttft_attainment:"
+                  f"{'n/a' if tta is None else format(tta, '.2f')}")
+
+    if args.trace_out is not None:
+        ov = session.overlap()
+        hf = ov["hidden_fraction"]
+        print(f"serve,overlap,transfers:{ov['transfers']},"
+              f"hidden_s:{ov['hidden_s']:.4f},"
+              f"exposed_s:{ov['exposed_s']:.4f},hidden_fraction:"
+              f"{'n/a' if hf is None else format(hf, '.2f')}")
+    if args.stats_json is not None:
+        with open(args.stats_json, "w") as f:
+            json.dump(session.stats(), f, indent=2, sort_keys=True,
+                      default=str)
+        print(f"serve,stats,{args.stats_json}")
+    session.close()   # exports the trace to --trace-out (telemetry.trace_path)
+    if args.trace_out is not None:
+        print(f"serve,trace,{args.trace_out}")
     return 0
 
 

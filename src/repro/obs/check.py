@@ -1,4 +1,4 @@
-"""Trace-file schema checker (CI gate for the telemetry subsystem).
+"""Trace-file schema checker for the telemetry subsystem.
 
 Validates that an exported trace is (a) well-formed Chrome trace-event
 JSON that Perfetto will open, and (b) consistent with the repo's span
@@ -8,7 +8,7 @@ request, and every transfer handle's events are ordered execution-start <=
 complete <= wait-resolution. (The transfer span covers execution only —
 queue time shows up as ``transfer.backpressure`` — so a blocked wait may
 legitimately *start* before its transfer span does; wait-start ordering
-is only an invariant for overlapped waits.) Run from CI as
+is only an invariant for overlapped waits.) Run as
 
     PYTHONPATH=src python -m repro.obs.check TRACE.json
 

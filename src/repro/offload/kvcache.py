@@ -195,8 +195,7 @@ class PagedKVCache:
         if pool is None:
             raise ValueError(
                 "PagedKVCache.create() requires a pool; construct caches "
-                "through repro.api.HyperOffloadSession.paged_kv "
-                "(mode='paged')")
+                "through repro.api.HyperOffloadSession.paged_kv")
         if device_pages is not None and device_pages < 1:
             raise ValueError("device_pages must be >= 1 (or None = all)")
         pool.transfer.ensure_depth(auto_depth(pages=n_pages))

@@ -16,7 +16,7 @@ it will prefetch soon with a high priority so reactive churn never evicts
 it — the graph-driven/reactive distinction at the heart of the paper.
 
 All traffic is counted (puts/gets/evictions, bytes in/out, per-tier
-occupancy and high-water mark); serving and benchmarks surface these via
+occupancy and high-water mark); serving and the session surface these via
 ``stats.snapshot()``. Synchronous movement (puts, spills, blocking gets)
 additionally lands in the transfer engine's per tier-pair table, so the
 calibration loop sees every byte the hierarchy moves, not just the async
@@ -405,7 +405,7 @@ class MemoryPoolManager:
         return st.used, st.capacity
 
     def snapshot(self) -> Dict[str, Any]:
-        """Stats + per-tier occupancy, for benchmarks/serving to print."""
+        """Stats + per-tier occupancy, merged into ``session.stats()``."""
         with self._lock:
             out: Dict[str, Any] = self.stats.snapshot()
             out["transfer"] = self.transfer.stats.snapshot()

@@ -157,7 +157,7 @@ def poisson_trace(n_requests: int, *, rate: float, vocab_size: int,
                   interactive_slo: Optional[SLOSpec] = None,
                   batch_slo: Optional[SLOSpec] = None,
                   seed: int = 0) -> List[Request]:
-    """Deterministic mixed-length Poisson arrival trace (benchmarks/tests):
+    """Deterministic mixed-length Poisson arrival trace (launcher/tests):
     exponential inter-arrival gaps at ``rate`` requests per unit of
     scheduler time, uniform prompt/decode lengths in the given ranges.
 

@@ -105,14 +105,14 @@ def test_config_validates_fields():
 
 
 def test_mode_resolves_planner_and_depth_defaults():
-    # offload modes plan every pool-resident KV tensor (the old hard-coded
+    # kv_offload plans every pool-resident KV tensor (the old hard-coded
     # min_bytes=1 at the PlanPrefetcher call site); resident keeps the
     # cost-model threshold
-    assert OffloadConfig(mode="paged").insertion_options() == PAGED_INSERTION
-    assert OffloadConfig(mode="kv_offload").insertion_options().min_bytes == 1
+    assert OffloadConfig(mode="kv_offload").insertion_options() \
+        == PAGED_INSERTION
     assert OffloadConfig().insertion_options().min_bytes == 1 << 20
     custom = InsertionOptions(min_bytes=7)
-    assert OffloadConfig(mode="paged",
+    assert OffloadConfig(mode="kv_offload",
                          insertion=custom).insertion_options() is custom
     # depth policy: auto derives from the consumer's shape, int pins
     auto = OffloadConfig()
@@ -172,7 +172,7 @@ def test_config_topology_roundtrip_and_validation():
     with pytest.raises(ValueError, match="capacities"):
         OffloadConfig(topology=topo, host_capacity=1 << 20)
     with pytest.raises(ValueError, match="pin_tier"):
-        OffloadConfig(mode="continuous", chunk_size=8, topology=topo,
+        OffloadConfig(chunk_size=8, topology=topo,
                       prefix_cache=PrefixCacheConfig(enable=True,
                                                      pin_tier="remote"))
     # a disabled prefix cache never vetoes a custom chain (its default
@@ -264,13 +264,13 @@ def test_session_auto_depth_grows_pinned_does_not(model_and_params):
 
 def test_session_scheduler_overrides(model_and_params):
     model, params = model_and_params
-    cfg = OffloadConfig(mode="continuous", max_seq=MAX_SEQ, max_batch=4)
+    cfg = OffloadConfig(max_seq=MAX_SEQ, max_batch=4)
     with HyperOffloadSession(cfg) as session:
         sched = session.scheduler(model, params, max_batch=2,
                                   prefill_budget=2)
         assert sched.cfg.max_batch == 2
         assert sched.cfg.prefill_budget == 2
-        assert sched.cfg.kv_offload is False              # continuous = resident
+        assert sched.cfg.kv_offload is False
         with pytest.raises(TypeError, match="not both"):
             session.scheduler(model, params, SchedulerConfig(), max_batch=2)
         with pytest.raises(TypeError, match="not both"):

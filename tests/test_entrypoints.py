@@ -1,6 +1,7 @@
 """Entry-point guards that hold on the CPU: the chip smoke check refuses
-to run without a TPU, and the compile-cache helper honours the
-environment before its fixed default."""
+to run without a TPU, the compile-cache helper honours the environment
+before its fixed default, and the serve launcher runs a traced smoke
+serve in each mode."""
 
 import runpy
 import sys
@@ -9,9 +10,11 @@ from pathlib import Path
 import jax
 import pytest
 
+from repro.launch import serve
 from repro.launch.compile_cache import (
     ENV_VAR, compile_cache_dir, enable_compile_cache,
 )
+from repro.obs.check import validate_file
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -49,3 +52,23 @@ def test_enable_compile_cache_sets_only_the_default(monkeypatch, tmp_path):
     assert enable_compile_cache() == str(ROOT / ".jax_cache")
     assert updates == [("jax_compilation_cache_dir",
                         str(ROOT / ".jax_cache"))]
+
+
+@pytest.mark.parametrize("mode", ["resident", "kv_offload"])
+def test_serve_launcher(mode, monkeypatch, tmp_path, capsys):
+    """``python -m repro.launch.serve --smoke`` serves a Poisson trace
+    through the scheduler in-process and writes a trace the schema
+    checker accepts; under kv_offload the printed hidden fraction is a
+    number, so pool transfers were traced."""
+    # the process-wide compile cache stays as the other tests left it
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    trace = tmp_path / "trace.json"
+    assert serve.main(["--smoke", "--requests", "4", "--mode", mode,
+                       "--trace-out", str(trace)]) == 0
+    assert validate_file(str(trace)) == []
+    out = capsys.readouterr().out
+    assert f"serve,{mode},requests:4," in out
+    overlap = next(line for line in out.splitlines()
+                   if line.startswith("serve,overlap,"))
+    if mode == "kv_offload":
+        assert not overlap.endswith("hidden_fraction:n/a")

@@ -387,7 +387,7 @@ def _preempting_requests():
 
 def _slo_config():
     from repro.slo import SLOConfig
-    return OffloadConfig(mode="continuous", max_batch=1, max_seq=32,
+    return OffloadConfig(max_batch=1, max_seq=32,
                          slo=SLOConfig(enable=True),
                          telemetry=TelemetryConfig(enable=True))
 
@@ -458,7 +458,7 @@ def test_profiler_capture_holds_scheduler_phases(model_and_params, tmp_path,
     spans as annotations, on the profiler's clock; telemetry off adds
     none."""
     from jax.profiler import ProfileData
-    cfg = OffloadConfig(mode="continuous", max_batch=2, max_seq=32,
+    cfg = OffloadConfig(max_batch=2, max_seq=32,
                         chunk_size=6,
                         telemetry=TelemetryConfig(enable=enable))
     opts = jax.profiler.ProfileOptions()

@@ -411,6 +411,7 @@ def test_codec_pool_records_wire_bytes_not_decoded():
     wire = x.size + 4
     e = p.put("pg", x, tier="host")
     assert e.nbytes == wire
+    assert x.nbytes >= 2 * wire          # int8 at least halves the wire
     snap = p.snapshot()
     assert snap["tier/host"]["used"] == wire
     assert snap["bytes_stored"] == wire

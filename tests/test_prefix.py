@@ -348,7 +348,7 @@ def test_prefix_requires_chunked_prefill(model_and_params):
 
 def test_prefix_config_validation_and_roundtrip():
     cfg = OffloadConfig(
-        mode="continuous", chunk_size=6,
+        chunk_size=6,
         prefix_cache=PrefixCacheConfig(enable=True, page_size=4,
                                        max_pages=64, min_match_pages=2,
                                        pin_tier="device"))
@@ -359,16 +359,17 @@ def test_prefix_config_validation_and_roundtrip():
         == cfg
 
     with pytest.raises(ValueError, match="chunk_size"):
-        OffloadConfig(mode="continuous",
-                      prefix_cache=PrefixCacheConfig(enable=True))
-    with pytest.raises(ValueError, match="scheduler mode"):
-        OffloadConfig(mode="resident", chunk_size=6,
-                      prefix_cache=PrefixCacheConfig(enable=True))
+        OffloadConfig(prefix_cache=PrefixCacheConfig(enable=True))
+    # both modes serve through the scheduler; a retired mode is refused
+    for mode in ("paged", "continuous"):
+        with pytest.raises(ValueError, match=f"'{mode}' not in"):
+            OffloadConfig(mode=mode, chunk_size=6,
+                          prefix_cache=PrefixCacheConfig(enable=True))
     # tier names are declarative (the topology's), so pin_tier validates
     # at the OffloadConfig level against the effective chain — but only
     # when the cache is actually enabled
     with pytest.raises(ValueError, match="pin_tier"):
-        OffloadConfig(mode="continuous", chunk_size=8,
+        OffloadConfig(chunk_size=8,
                       prefix_cache=PrefixCacheConfig(enable=True,
                                                      pin_tier="nvram"))
     OffloadConfig(prefix_cache=PrefixCacheConfig(pin_tier="nvram"))
@@ -378,7 +379,7 @@ def test_prefix_config_validation_and_roundtrip():
 
 def test_session_builds_and_surfaces_prefix_cache(model_and_params):
     model, params = model_and_params
-    cfg = OffloadConfig(mode="continuous", max_batch=2, max_seq=MAX_SEQ,
+    cfg = OffloadConfig(max_batch=2, max_seq=MAX_SEQ,
                         chunk_size=6,
                         prefix_cache=PrefixCacheConfig(enable=True,
                                                        page_size=4))

@@ -384,6 +384,38 @@ def test_no_starvation_at_3x_overload(model_and_params):
     sched.close()
 
 
+def test_slo_beats_fifo_goodput_and_ttft_at_3x_overload(model_and_params):
+    """On one annotated trace at 3x the scheduler's service capacity, the
+    SLO-aware policy beats FIFO on goodput (deadline-met tokens per
+    virtual step) and on interactive TTFT attainment. Both run on the
+    virtual clock, so the comparison is deterministic."""
+    model, params = model_and_params
+    max_batch, max_seq, chunk = 4, 64, 8
+    prompt_lens, new_tokens = (4, 16), (8, 24)
+    # service capacity: slots over the mean steps a request holds one
+    # (prefill chunks plus decode steps)
+    mean_steps = sum(prompt_lens) / 2 / chunk + sum(new_tokens) / 2
+    trace = poisson_trace(
+        16, rate=3 * max_batch / mean_steps, vocab_size=CFG.vocab_size,
+        prompt_lens=prompt_lens, new_tokens=new_tokens, prompt_quantum=4,
+        interactive_fraction=0.35,
+        interactive_slo=SLOSpec("interactive", ttft_deadline=10.0),
+        batch_slo=SLOSpec("batch"), seed=11)
+    results = []
+    for slo in (SLOConfig(), SLOConfig(enable=True)):        # FIFO, SLO
+        with HyperOffloadSession(OffloadConfig(
+                max_batch=max_batch, max_seq=max_seq, prefill_budget=2,
+                chunk_size=chunk, slo=slo)) as session:
+            sched = session.scheduler(model, params)
+            sched.run(list(trace))
+            att = attainment_summary(sched.finished.values())
+            results.append((att["met_tokens"] / sched.now,
+                            att["classes"]["interactive"]["ttft_attainment"]))
+    (fifo_goodput, fifo_ttft), (slo_goodput, slo_ttft) = results
+    assert slo_goodput > fifo_goodput
+    assert slo_ttft > fifo_ttft
+
+
 def test_infeasible_request_shed_before_admission(model_and_params):
     """A TTFT deadline no admission could meet — 24 prompt tokens at 4
     per step (boost disabled) cannot land a first token inside 3 steps —
@@ -435,16 +467,17 @@ def test_shed_disabled_admits_and_misses(model_and_params):
 
 
 def test_offload_config_slo_round_trip_and_mode_gate():
-    cfg = OffloadConfig(mode="continuous",
-                        slo=SLOConfig(enable=True, max_prefill_boost=2.0,
+    cfg = OffloadConfig(slo=SLOConfig(enable=True, max_prefill_boost=2.0,
                                       max_preempt_per_step=2))
     back = OffloadConfig.from_dict(cfg.to_dict())
     assert back.slo == cfg.slo and back.slo.max_preempt_per_step == 2
     assert OffloadConfig().slo == SLOConfig()      # default: disabled
-    with pytest.raises(ValueError, match="slo.enable"):
-        OffloadConfig(mode="resident", slo=SLOConfig(enable=True))
-    with pytest.raises(ValueError, match="slo.enable"):
-        OffloadConfig(mode="paged", slo=SLOConfig(enable=True))
+    # both modes serve through the scheduler; a retired mode is refused
+    for mode in ("resident", "kv_offload"):
+        assert OffloadConfig(mode=mode, slo=SLOConfig(enable=True)).slo.enable
+    for mode in ("paged", "continuous"):
+        with pytest.raises(ValueError, match=f"'{mode}' not in"):
+            OffloadConfig(mode=mode, slo=SLOConfig(enable=True))
 
 
 def test_session_slo_stats_exposed(model_and_params):
@@ -462,7 +495,7 @@ def test_session_slo_stats_exposed(model_and_params):
                 slo=SLOSpec("interactive", ttft_deadline=2.0)),
     ]
     session = HyperOffloadSession(OffloadConfig(
-        mode="continuous", max_batch=1, max_seq=MAX_SEQ,
+        max_batch=1, max_seq=MAX_SEQ,
         slo=SLOConfig(enable=True)))
     sched = session.scheduler(model, params)
     sched.run(reqs)
@@ -480,7 +513,7 @@ def test_slo_disabled_keeps_fifo_counters_zero(model_and_params):
     the session snapshot."""
     model, params = model_and_params
     session = HyperOffloadSession(OffloadConfig(
-        mode="continuous", max_batch=1, max_seq=MAX_SEQ))
+        max_batch=1, max_seq=MAX_SEQ))
     sched = session.scheduler(model, params)
     sched.run([Request(tokens=np.ones((4,), np.int32), max_new_tokens=2,
                        slo=SLOSpec("interactive", ttft_deadline=1.0))])
